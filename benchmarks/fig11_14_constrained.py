@@ -55,4 +55,7 @@ def run(seeds=(0, 1, 2)) -> bool:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -130,4 +130,7 @@ def run() -> bool:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(0 if run() else 1)

@@ -40,4 +40,7 @@ def run(path: str = "results/dryrun.json") -> bool:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

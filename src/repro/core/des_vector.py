@@ -20,9 +20,9 @@ Batching (the ``engine.p1_solve_batch`` style): all M clusters advance in ONE
 ``lax.scan`` — step k of lane i is lane i's k-th customer (each lane carries
 its own inter-arrival gaps, so lanes never synchronize). Customer counts pad
 to a pow2 with a per-step validity mask; server counts pad to a pow2 with
-masked slots pinned at a large sentinel so they never win the min. Hosts
-without a working JAX fall back to a chunked NumPy loop over the same arrays
-(still batched across lanes, ~3-10x the event engine; JAX is 20-100x).
+masked slots pinned at a large sentinel so they never win the min. A
+chunked NumPy loop over the same arrays runs only when asked for with
+``backend="numpy"`` (the tests pin the two backends against each other).
 
 Hand-off invariants at ``configure()``/``retire()``/``activate()`` segment
 boundaries (DESIGN.md §10):
@@ -57,6 +57,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arrivals import ArrivalStream, parse_arrival, validate_service
@@ -75,14 +77,12 @@ from repro.core.lifecycle import (
 )
 
 _BIG = 1e30  # masked server-slot sentinel: never wins the min, absorbs gaps
+_BACKENDS = ("jax", "numpy")
 
-try:  # JAX scan backend (x64 is enabled by repro.core at import)
-    import jax
-    import jax.numpy as jnp
 
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - the container always has jax
-    _HAS_JAX = False
+def _check_backend(backend: str) -> None:
+    if backend not in _BACKENDS:
+        raise ValueError(f"backend must be one of {_BACKENDS}, got {backend!r}")
 
 
 def _pad_pow2(k: int) -> int:
@@ -123,100 +123,91 @@ def _segment_scan_numpy(W0, smask, gaps, svcs, valid):
     return W, waits
 
 
-if _HAS_JAX:
+@jax.jit
+def _segment_scan_jax(W0, smask, gaps, svcs, valid):
+    j = jnp.arange(W0.shape[1])[None, :]
 
-    @jax.jit
-    def _segment_scan_jax(W0, smask, gaps, svcs, valid):
-        j = jnp.arange(W0.shape[1])[None, :]
+    def step(W, xs):
+        g, s, v = xs
+        Wd = jnp.maximum(W - g[:, None], 0.0)
+        Wd = jnp.where(smask, Wd, _BIG)
+        wait = Wd[:, 0]
+        new = wait + s
+        # gather-free sorted insert (see _kw_step_np)
+        hi = jnp.minimum(
+            jnp.concatenate(
+                [Wd[:, 1:], jnp.full_like(Wd[:, :1], jnp.inf)], axis=1
+            ),
+            new[:, None],
+        )
+        Wn = jnp.where(j == 0, hi, jnp.maximum(Wd, hi))
+        Wn = jnp.where(smask, Wn, _BIG)
+        return jnp.where(v[:, None], Wn, W), jnp.where(v, wait, 0.0)
 
-        def step(W, xs):
-            g, s, v = xs
-            Wd = jnp.maximum(W - g[:, None], 0.0)
-            Wd = jnp.where(smask, Wd, _BIG)
-            wait = Wd[:, 0]
-            new = wait + s
-            # gather-free sorted insert (see _kw_step_np)
-            hi = jnp.minimum(
-                jnp.concatenate(
-                    [Wd[:, 1:], jnp.full_like(Wd[:, :1], jnp.inf)], axis=1
-                ),
-                new[:, None],
-            )
-            Wn = jnp.where(j == 0, hi, jnp.maximum(Wd, hi))
-            Wn = jnp.where(smask, Wn, _BIG)
-            return jnp.where(v[:, None], Wn, W), jnp.where(v, wait, 0.0)
-
-        return jax.lax.scan(step, W0, (gaps, svcs, valid))
+    return jax.lax.scan(step, W0, (gaps, svcs, valid))
 
 
-def segment_scan(W0, smask, gaps, svcs, valid, backend="auto"):
-    """Run the batched recurrence over one segment. ``backend="auto"`` uses
-    JAX when importable, else the chunked NumPy loop."""
-    if backend == "auto":
-        backend = "jax" if _HAS_JAX else "numpy"
+def segment_scan(W0, smask, gaps, svcs, valid, backend="jax"):
+    """Run the batched recurrence over one segment: the jitted ``lax.scan``,
+    or the chunked NumPy loop when ``backend="numpy"``."""
+    _check_backend(backend)
     if backend == "jax":
-        if not _HAS_JAX:
-            raise RuntimeError("backend='jax' requested but jax is unavailable")
         Wf, waits = _segment_scan_jax(W0, smask, gaps, svcs, valid)
         return np.asarray(Wf), np.asarray(waits)
-    if backend != "numpy":
-        raise ValueError(f"backend must be auto|jax|numpy, got {backend!r}")
     return _segment_scan_numpy(W0, smask, gaps, svcs, valid)
 
 
 # ----------------------------------------------------------------------------
 # Candidate-batched rollouts (DESIGN.md §14)
 # ----------------------------------------------------------------------------
-if _HAS_JAX:
+@jax.jit
+def _rollout_scan_jax(W0, gaps, svcs_unit, inv_mu):
+    """The segment scan with a CANDIDATE axis B (the
+    ``engine.ip_solve_rows`` idiom applied to simulation): W0 is
+    (Mp, n_pad, B), per-customer xs gaps/svcs_unit are (Kp, Mp) and
+    SHARED by every candidate — the common-random-number pairing — and
+    inv_mu (Mp, B) rescales the unit-rate service draws inside the step,
+    so the materialized draw arrays stay K·M, never K·B·M. B is the
+    MINOR-MOST (contiguous) axis on purpose: every op in the step body is
+    then uniform across the fastest-varying dimension — the server-slot
+    shift of the sorted insert lands on a middle axis — which is what
+    lets the CPU backend emit packed SIMD for the candidate lanes (~4x
+    over the candidate-major layout at B = 32). Returns
+    (W_final (Mp, n_pad, B), waits (Kp, Mp, B))."""
+    j = jnp.arange(W0.shape[1])[None, :, None]
 
-    @jax.jit
-    def _rollout_scan_jax(W0, gaps, svcs_unit, inv_mu):
-        """The segment scan with a CANDIDATE axis B (the
-        ``engine.ip_solve_rows`` idiom applied to simulation): W0 is
-        (Mp, n_pad, B), per-customer xs gaps/svcs_unit are (Kp, Mp) and
-        SHARED by every candidate — the common-random-number pairing — and
-        inv_mu (Mp, B) rescales the unit-rate service draws inside the step,
-        so the materialized draw arrays stay K·M, never K·B·M. B is the
-        MINOR-MOST (contiguous) axis on purpose: every op in the step body is
-        then uniform across the fastest-varying dimension — the server-slot
-        shift of the sorted insert lands on a middle axis — which is what
-        lets the CPU backend emit packed SIMD for the candidate lanes (~4x
-        over the candidate-major layout at B = 32). Returns
-        (W_final (Mp, n_pad, B), waits (Kp, Mp, B))."""
-        j = jnp.arange(W0.shape[1])[None, :, None]
+    def step(W, xs):
+        g, su = xs
+        # Leaner than _kw_step_np in three exact ways (results for every
+        # consumed entry are bitwise identical):
+        #  * no masked-slot pinning: smask is fixed for a rollout, so the
+        #    _BIG sentinels only DECAY by Sum(g) <= horizon over the whole
+        #    run — 1e30 minus a few hundred still loses every min and wins
+        #    every max, and the insert identity only consumes order;
+        #  * no validity select on the carry: invalid rows are zero-padded
+        #    (g = 0, su = 0), so the step removes the current min and
+        #    re-inserts the SAME value — an exact no-op by the identity;
+        #  * no validity select on the wait output: invalid/padded slots
+        #    emit garbage that no scorer reads (the post-processing slices
+        #    [warmup:k_i) per lane).
+        Wd = jnp.maximum(W - g[:, None, None], 0.0)
+        wait = Wd[:, 0, :]
+        new = wait + su[:, None] * inv_mu
+        # gather-free sorted insert (see _kw_step_np)
+        hi = jnp.minimum(
+            jnp.concatenate(
+                [Wd[:, 1:, :], jnp.full_like(Wd[:, :1, :], jnp.inf)], axis=1
+            ),
+            new[:, None, :],
+        )
+        Wn = jnp.where(j == 0, hi, jnp.maximum(Wd, hi))
+        return Wn, wait
 
-        def step(W, xs):
-            g, su = xs
-            # Leaner than _kw_step_np in three exact ways (results for every
-            # consumed entry are bitwise identical):
-            #  * no masked-slot pinning: smask is fixed for a rollout, so the
-            #    _BIG sentinels only DECAY by Sum(g) <= horizon over the whole
-            #    run — 1e30 minus a few hundred still loses every min and wins
-            #    every max, and the insert identity only consumes order;
-            #  * no validity select on the carry: invalid rows are zero-padded
-            #    (g = 0, su = 0), so the step removes the current min and
-            #    re-inserts the SAME value — an exact no-op by the identity;
-            #  * no validity select on the wait output: invalid/padded slots
-            #    emit garbage that no scorer reads (the post-processing slices
-            #    [warmup:k_i) per lane).
-            Wd = jnp.maximum(W - g[:, None, None], 0.0)
-            wait = Wd[:, 0, :]
-            new = wait + su[:, None] * inv_mu
-            # gather-free sorted insert (see _kw_step_np)
-            hi = jnp.minimum(
-                jnp.concatenate(
-                    [Wd[:, 1:, :], jnp.full_like(Wd[:, :1, :], jnp.inf)], axis=1
-                ),
-                new[:, None, :],
-            )
-            Wn = jnp.where(j == 0, hi, jnp.maximum(Wd, hi))
-            return Wn, wait
-
-        return jax.lax.scan(step, W0, (gaps, svcs_unit))
+    return jax.lax.scan(step, W0, (gaps, svcs_unit))
 
 
 def _rollout_scan_numpy(W0, smask, gaps, svcs_unit, valid, inv_mu):
-    """NumPy fallback: the (Mp, B) candidate grid flattened to Mp·B lanes of
+    """NumPy backend: the (Mp, B) candidate grid flattened to Mp·B lanes of
     the existing per-step kernel, so both backends share ``_kw_step_np``."""
     Mp, n_pad, B = W0.shape
     Kp = gaps.shape[0]
@@ -376,7 +367,7 @@ def rollout_candidates(
     arrival=None,
     service: str = "exp",
     h2_scv: float = 4.0,
-    backend: str = "auto",
+    backend: str = "jax",
 ) -> RolloutResult:
     """Simulate B candidate allocations × M apps over SHARED CRN draws in ONE
     batched Kiefer–Wolfowitz scan (DESIGN.md §14).
@@ -411,6 +402,7 @@ def rollout_candidates(
         raise ValueError(f"need {M} names and (M,) lam for (B, {M}) candidates")
     if np.any(mu <= 0.0):
         raise ValueError("every candidate mu must be > 0")
+    _check_backend(backend)
     if horizon_s <= 0.0 or warmup_s < 0.0 or warmup_s >= horizon_s:
         raise ValueError("need horizon_s > 0 and 0 <= warmup_s < horizon_s")
     validate_service(service, h2_scv)
@@ -445,9 +437,8 @@ def rollout_candidates(
             gaps[:k, i] = np.diff(times, prepend=t0)
             su[:k, i] = s
             valid[:k, i] = True
-        if _HAS_JAX:  # commit once so cache hits skip the host->device copy
-            gaps, su, valid = (jnp.asarray(gaps), jnp.asarray(su),
-                               jnp.asarray(valid))
+        # commit once so cache hits skip the host->device copy
+        gaps, su, valid = jnp.asarray(gaps), jnp.asarray(su), jnp.asarray(valid)
         cached = (arrs, svcs_u, n_arrivals, K, gaps, su, valid)
     _CRN_CACHE[key] = cached
     while len(_CRN_CACHE) > _CRN_CACHE_MAX:
@@ -475,23 +466,17 @@ def rollout_candidates(
     inv_mu[:M] = 1.0 / mu.T
     W0 = np.where(smask, 0.0, _BIG)
 
-    if backend == "auto":
-        backend = "jax" if _HAS_JAX else "numpy"
     if backend == "jax":
-        if not _HAS_JAX:
-            raise RuntimeError("backend='jax' requested but jax is unavailable")
         _, waits = _rollout_scan_jax(W0, gaps, su, inv_mu)
         waits = waits[:K]  # drop the pow2 tail before any transfer
         waits.block_until_ready()  # the returned simulation IS complete;
         # only the scoring stats (transfer + percentiles) are lazy
-    elif backend == "numpy":
+    else:
         _, waits = _rollout_scan_numpy(
             W0, smask, np.asarray(gaps), np.asarray(su), np.asarray(valid),
             inv_mu,
         )
         waits = waits[:K]
-    else:
-        raise ValueError(f"backend must be auto|jax|numpy, got {backend!r}")
 
     served = n_srv > 0  # (B, M): zero-server lanes never start their work
     return RolloutResult(
@@ -635,7 +620,7 @@ class VectorFleetSimulator(FleetSimulator):
     and stats contract, but ``run_until`` advances one whole stationary
     segment per call through the batched recurrence instead of an event loop.
 
-    ``backend`` pins the scan implementation ("jax" | "numpy" | "auto").
+    ``backend`` pins the scan implementation: "jax" (default) or "numpy".
 
     One intentional pre-``drain()`` difference from the oracle: a customer's
     response is final once its service STARTS, so ``responses()`` before
@@ -651,15 +636,14 @@ class VectorFleetSimulator(FleetSimulator):
         engine: str = "vector",
         service: str = "exp",
         h2_scv: float = 4.0,
-        backend: str = "auto",
+        backend: str = "jax",
         arrival=None,
         lifecycle=None,
         failures=None,
     ):
         if engine != "vector":
             raise ValueError(f"VectorFleetSimulator is engine='vector', got {engine!r}")
-        if backend not in ("auto", "jax", "numpy"):
-            raise ValueError(f"backend must be auto|jax|numpy, got {backend!r}")
+        _check_backend(backend)
         super().__init__(
             seed=seed, service=service, h2_scv=h2_scv, arrival=arrival,
             lifecycle=lifecycle, failures=failures,

@@ -201,6 +201,19 @@ def p1_rho(x, packed, n):
 _NEWTON_DAMP = 1e-9  # diagonal damping shared by the dense and structured paths
 
 
+def _check_solver(solver: str) -> None:
+    """The dense Newton is a CPU test reference: its f64 ``jnp.linalg.solve``
+    has no TPU lowering (XLA's TPU LU decomposition takes F32/C64 only), so
+    asking for it on a TPU fails here rather than deep in the compiler."""
+    if solver not in ("structured", "dense"):
+        raise ValueError(f"solver must be 'structured' or 'dense', got {solver!r}")
+    if solver == "dense" and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "solver='dense' is a CPU test reference: its float64 LU solve does "
+            "not compile for TPU; use solver='structured'"
+        )
+
+
 def _newton_direction_structured(x, t, packed, n, caps_cpu, caps_mem, power_span, alpha, beta,
                                  width: int | None = None, tail_q: float = 0.0):
     """Analytic Newton direction H⁻¹g for the P1 barrier in O(M).
@@ -326,9 +339,10 @@ def _ip_core(x0, packed, n, caps_cpu, caps_mem, power_span, alpha, beta, n_outer
     jax.hessian + O((2M)³) jnp.linalg.solve escape hatch kept for parity
     testing (tests/test_structured_newton.py pins the two within 1e-6).
 
-    ``width`` (static) narrows every Erlang-C logsumexp from MAX_SERVERS to
-    the given width — exact whenever all container counts stay below it
-    (queueing._log_sum_k), and the dominant term in fleet-scale wall clock."""
+    ``width`` (static) narrows every Erlang-C recurrence from MAX_SERVERS
+    steps to the given width — exact whenever all container counts stay
+    below it (queueing._erlang_c), and the dominant term in fleet-scale wall
+    clock."""
 
     def strictly_feasible(x):
         _, slacks = p1_barrier(x, 1.0, packed, n, caps_cpu, caps_mem, power_span, alpha, beta,
@@ -509,20 +523,18 @@ def _ip_solve_rows_sharded(
     """shard_map wrapper: row-stacked operands split along ``axis`` of
     ``mesh`` (the mesh idiom of launch/mesh.py), scalars replicated. Rows are
     independent, so out_specs is a plain gather — no collectives. The node
-    count must be divisible by the axis size; the placement layer's pow2
-    node padding guarantees that on pow2 meshes."""
-    from jax.experimental.shard_map import shard_map
+    count must be divisible by the axis size (``ip_solve_rows`` checks)."""
     from jax.sharding import PartitionSpec as P
 
     row = P(axis)  # pytree prefix: applies to every leaf of packed_rows too
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_rows_core, n_outer=n_outer, n_inner=n_inner, solver=solver,
                 t0=t0, width=width),
         mesh=mesh,
         in_specs=(row, row, row, row, row, rep, rep, rep),
         out_specs=(row, row, row),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         x0, packed_rows, n, caps_cpu, caps_mem,
@@ -542,10 +554,16 @@ def ip_solve_rows(
     of (N, M)/(N, M, 3) arrays (plus the (N, M) "mask" sentinel field),
     n (N, M), caps_cpu/caps_mem (N,); power_span/alpha/beta are fleet-wide
     scalars. Returns (x* (N, 2M), utility (N,), ws (N, M))."""
+    _check_solver(solver)
     if mesh is None:
         return _ip_solve_rows(
             x0, packed_rows, n, caps_cpu, caps_mem, power_span, alpha, beta,
             n_outer=n_outer, n_inner=n_inner, solver=solver, t0=t0, width=width,
+        )
+    if x0.shape[0] % mesh.shape[mesh_axis]:
+        raise ValueError(
+            f"{x0.shape[0]} rows do not split evenly over the {mesh.shape[mesh_axis]} "
+            f"devices of mesh axis {mesh_axis!r}; pad the row batch"
         )
     return _ip_solve_rows_sharded(
         x0, packed_rows, n, caps_cpu, caps_mem, power_span, alpha, beta,
@@ -920,7 +938,7 @@ def p1_solve_batch(
     head of the hint chain; rows where a hinted phase-1 fails fall back to
     the caller's ``c_hint`` and finally the plain waterfill, so hint sources
     only ever add feasible rows. ``max_servers`` narrows every Erlang-C
-    logsumexp from queueing.MAX_SERVERS to the given static width — EXACT
+    recurrence from queueing.MAX_SERVERS to the given static width — EXACT
     (not approximate) because every count in the batch must stay ≤ it, which
     is validated eagerly; callers should pass a pow2 so distinct fleets share
     one jit cache entry. ``tail_q`` (static) swaps the per-app latency term
@@ -936,6 +954,7 @@ def p1_solve_batch(
         raise ValueError(
             f"on_infeasible must be 'mask' or 'raise', got {on_infeasible!r}"
         )
+    _check_solver(solver)
     prof_outer, prof_inner = P1_PROFILES[profile]
     n_outer = prof_outer if n_outer is None else n_outer
     n_inner = prof_inner if n_inner is None else n_inner
@@ -1082,8 +1101,8 @@ def sp1_solve_batch(apps, caps: ServerCaps, alpha: float, beta: float, iters: in
 def _phi_grid(lam, mu, c, power_span, caps_cpu, alpha, beta, ns, width=None):
     """Φ(N) of Eq. (23) on an (M, K) grid of container counts. ``alpha`` is a
     per-app (M,) latency weight (a scalar is broadcast by the caller).
-    ``width``: static Erlang-sum width — K itself is exact, since no grid
-    count exceeds K (see queueing._log_sum_k)."""
+    ``width``: static Erlang recurrence width — K itself is exact, since no
+    grid count exceeds K (see queueing._erlang_c)."""
 
     def per_app(lam_i, mu_i, c_i, alpha_i):
         def per_n(n):

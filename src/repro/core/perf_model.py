@@ -88,6 +88,26 @@ def _inv_softplus(y):
     return y + jnp.log(-jnp.expm1(-y))
 
 
+def _solve_small(A, b):
+    """Solve A x = b for a small static n by Gaussian elimination with partial
+    pivoting, unrolled over rows. The LM systems are 2×2 to 4×4 per start,
+    and an f64 LU call does not compile on TPU; this lowers to elementwise
+    ops and selects that run in any precision."""
+    n = A.shape[0]
+    Ab = jnp.concatenate([A, b[:, None]], axis=1)
+    rows = jnp.arange(n)
+    for k in range(n):
+        p = k + jnp.argmax(jnp.abs(Ab[k:, k]))  # pivot row
+        swap = jnp.where(rows == k, p, jnp.where(rows == p, k, rows))
+        Ab = Ab[swap]
+        f = jnp.where(rows > k, Ab[:, k] / Ab[k, k], 0.0)
+        Ab = Ab - f[:, None] * Ab[k][None, :]
+    x = jnp.zeros_like(b)
+    for k in reversed(range(n)):
+        x = x.at[k].set((Ab[k, n] - jnp.dot(Ab[k, :n], x)) / Ab[k, k])
+    return x
+
+
 @dataclasses.dataclass
 class FitResult:
     family: str
@@ -128,7 +148,7 @@ def _lm_fit(theta0, cpu, mem, y, fn=None, positive=True, iters=200):
         n = theta.shape[0]
 
         def try_lambda(lam):
-            delta = jnp.linalg.solve(JTJ + lam * jnp.eye(n, dtype=theta.dtype), g)
+            delta = _solve_small(JTJ + lam * jnp.eye(n, dtype=theta.dtype), g)
             cand = theta - delta
             return cand, loss(cand)
 

@@ -19,9 +19,9 @@ pow2 sentinel padding (node axis)
     freezes sentinel coordinates — padded rows match standalone solves to
     fp precision (tests/test_placement.py).
 narrow Erlang width
-    Every Erlang-C logsumexp is narrowed from queueing.MAX_SERVERS (512) to
-    the pow2 ceiling of the fleet's largest container count — EXACT, and the
-    dominant wall-clock lever (~6x on the interior point).
+    Every Erlang-C recurrence is narrowed from queueing.MAX_SERVERS (512)
+    steps to the pow2 ceiling of the fleet's largest container count —
+    EXACT, and the dominant wall-clock lever on the interior point.
 incremental re-scoring
     ``replan`` re-solves ONLY the nodes touched by a λ change or migration,
     warm-hinted from the current solution; untouched nodes keep their
@@ -356,6 +356,9 @@ class FleetPlanner:
 
         B = sub.size
         Bp = _pad_pow2(B)
+        if self.mesh is not None:  # the row batch must split evenly over the mesh
+            k = self.mesh.shape[self.mesh_axis]
+            Bp = -(-Bp // k) * k
         self._width = self._erlang_width()
 
         def pad(a):

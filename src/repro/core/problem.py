@@ -12,8 +12,10 @@ special case.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -83,22 +85,6 @@ def service_rate(app: App, r_cpu, r_mem):
     return 1.0 / (app.xbar * d_s)
 
 
-def app_terms(app: App, n, r_cpu, r_mem, caps: ServerCaps, alpha: float, beta: float,
-              tail_q: float = 0.0):
-    """Returns (ws_seconds, dP_watts, utility_term) for one app. ``tail_q``
-    swaps the latency factor for the analytic response-time quantile
-    surrogate (queueing.erlang_wait_quantile, DESIGN.md §14); 0.0 keeps the
-    paper's mean Ws."""
-    mu = service_rate(app, r_cpu, r_mem)
-    if tail_q:
-        ws = queueing.erlang_wait_quantile(n, app.lam, mu, q=tail_q)
-    else:
-        ws = queueing.erlang_ws(n, app.lam, mu)
-    dp = delta_power(n, r_cpu, caps.r_cpu, caps.power)
-    term = alpha * ws + beta * dp / app.lam
-    return ws, dp, term
-
-
 def p95_surrogate_s(apps: Sequence[App], n, r_cpu, r_mem, q: float = 0.95) -> np.ndarray:
     """Per-app analytic response-time quantile surrogate at an allocation —
     the model-side tail prediction the DES's ``achieved_p95_s`` is compared
@@ -129,17 +115,25 @@ def utility(
     term to α·w_i·Ws_i (the priority-weighted CRMS objective); None keeps
     the paper's unweighted objective. ``tail_q``: quantile-surrogate latency
     term (0.0 = mean; the returned per-app latency vector then holds the
-    surrogate quantiles, not mean Ws)."""
-    total = 0.0
-    ws_all, dp_all = [], []
-    for i, app in enumerate(apps):
-        a_i = alpha if weights is None else alpha * float(weights[i])
-        ws, dp, term = app_terms(app, n[i], r_cpu[i], r_mem[i], caps, a_i, beta,
-                                 tail_q=tail_q)
-        ws_all.append(ws)
-        dp_all.append(dp)
-        total = total + term
-    return total, jnp.stack(ws_all), jnp.stack(dp_all)
+    surrogate quantiles, not mean Ws).
+
+    Every app is evaluated at once as an (M,) vector: on a TPU, float64
+    arithmetic on rank-0 arrays is far less precise than on vectors (exp
+    4.6e-8 against 6.7e-15 relative, measured on v5e)."""
+    f64 = jnp.float64
+    kappa = jnp.asarray([a.kappa for a in apps], f64).T
+    lam = jnp.asarray([a.lam for a in apps], f64)
+    xbar = jnp.asarray([a.xbar for a in apps], f64)
+    n = jnp.asarray(n, f64)
+    r_cpu = jnp.asarray(r_cpu, f64)
+    mu = 1.0 / (xbar * eq1_latency(kappa, r_cpu, jnp.asarray(r_mem, f64)) * 1e-3)
+    if tail_q:
+        ws = jax.vmap(partial(queueing.erlang_wait_quantile, q=tail_q))(n, lam, mu)
+    else:
+        ws = jax.vmap(queueing.erlang_ws)(n, lam, mu)
+    dp = delta_power(n, r_cpu, caps.r_cpu, caps.power)
+    a = alpha if weights is None else alpha * jnp.asarray(weights, f64)
+    return jnp.sum(a * ws + beta * dp / lam), ws, dp
 
 
 def check_feasible(apps, n, r_cpu, r_mem, caps: ServerCaps, tol: float = 1e-6):

@@ -1,10 +1,18 @@
 """M/M/N queueing (Eqs. 4-7 of the paper), numerically stable and differentiable.
 
 The paper's Eq. (4)-(5) use factorials directly; for container counts beyond ~20
-that overflows, so everything here is computed in log-space with ``gammaln``.
-All functions are jit/vmap/grad-safe: ``N`` may be a traced integer (or float —
-the continuous extension via Gamma(N+1) is used by convexity tests), and the sum
-over k=0..N-1 is a masked fixed-width logsumexp.
+that overflows. The solver-path quantities (Ws, Ls, the waiting probability C
+and their derivatives) therefore come from the Erlang-B recurrence
+
+    B_0 = 1,   B_k = a·B_{k-1} / (k + a·B_{k-1}),   C = B_N / (1 - rho·(1 - B_N))
+
+which needs only multiplies, adds and divides, all on values in [0, 1]. A
+float64 transcendental (log, exp, lgamma) expands into a long f32 sequence on a
+TPU, which has no float64 units, and the log-space form of Eq. (5) puts about a
+dozen of them into every Erlang evaluation; the recurrence puts in none, so the
+solver programs compile in seconds rather than minutes there. All functions are
+jit/vmap/grad-safe: ``N`` may be a traced integer-valued float, and the
+recurrence runs a fixed number of masked steps (``width``).
 
 Conventions
 -----------
@@ -15,6 +23,8 @@ rho : lam / (N mu) — must be < 1 for stability; unstable inputs return +inf.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax.scipy.special import gammaln
@@ -24,17 +34,31 @@ from jax.scipy.special import gammaln
 MAX_SERVERS = 512
 
 
-def _log_sum_k(N, log_a, width: int | None = None):
-    """log Σ_{k=0}^{N-1} a^k / k!  as a masked logsumexp (fixed width).
+@partial(jax.jit, static_argnames=("width",))
+def _erlang_c(N, a, rho_s, width: int | None = None):
+    """Erlang-C waiting probability C(N, a) from the Erlang-B recurrence,
+    evaluated at the clamped load ``rho_s`` = min(a/N, 1 - eps).
 
-    ``width`` narrows the masked sum from MAX_SERVERS (the default) to a
-    caller-chosen static width. EXACT, not an approximation, whenever
-    N <= width: masked terms contribute exp(-inf) = 0 to the logsumexp, so
-    dropping them changes nothing. The fleet placement layer passes the
-    pow2 ceiling of its largest container count (~16 instead of 512), which
-    is the difference between a ~6x-slower and a sub-second 1000-node solve
-    on CPU — every Erlang evaluation in the interior point pays this width.
+    The recurrence runs ``width`` steps (MAX_SERVERS by default) and masks
+    every step k > N, so it is EXACT, not an approximation, whenever
+    N <= width: masked steps carry B through unchanged. The fleet placement
+    layer passes the pow2 ceiling of its largest container count (~16
+    instead of 512) — every Erlang evaluation in the interior point pays
+    this width.
     """
+    ks = jnp.arange(1, (MAX_SERVERS if width is None else width) + 1, dtype=a.dtype)
+
+    def step(B, k):
+        return jnp.where(k <= N, a * B / (k + a * B), B), None
+
+    B, _ = jax.lax.scan(step, jnp.ones_like(a), ks)
+    return B / (1.0 - rho_s * (1.0 - B))
+
+
+def _log_sum_k(N, log_a, width: int | None = None):
+    """log Σ_{k=0}^{N-1} a^k / k!  as a masked logsumexp (fixed width) — the
+    log-space head of Eq. (5), used by ``erlang_pi0``. Exact for N <= width:
+    masked terms contribute exp(-inf) = 0."""
     ks = jnp.arange(MAX_SERVERS if width is None else width, dtype=log_a.dtype)
     logs = ks * log_a - gammaln(ks + 1.0)
     mask = ks < N
@@ -57,41 +81,26 @@ def erlang_pi0(N, lam, mu, width: int | None = None):
     return jnp.exp(log_pi0)
 
 
-def _erlang_log_lq(N, lam, mu, width: int | None = None):
-    """log Lq where Lq = pi0 * a^N * rho / (N! (1-rho)^2)   (queue part of Eq. 4)."""
+def erlang_ls(N, lam, mu, width: int | None = None):
+    """Eq. (4): expected number of requests in the system. +inf when rho >= 1.
+
+    Lq = C·rho/(1 - rho) with C the Erlang-C waiting probability."""
     dtype = jnp.result_type(float)
     N = jnp.asarray(N, dtype=dtype)
     lam = jnp.asarray(lam, dtype=dtype)
     mu = jnp.asarray(mu, dtype=dtype)
-    log_a = jnp.log(lam) - jnp.log(mu)
-    rho = lam / (N * mu)
-    rho_safe = jnp.minimum(rho, 1.0 - 1e-9)
-    log_head = _log_sum_k(N, log_a, width)
-    log_tail = N * log_a - gammaln(N + 1.0) - jnp.log1p(-rho_safe)
-    log_pi0 = -jnp.logaddexp(log_head, log_tail)
-    log_lq = (
-        N * log_a
-        - gammaln(N + 1.0)
-        + jnp.log(rho_safe)
-        - 2.0 * jnp.log1p(-rho_safe)
-        + log_pi0
-    )
-    return log_lq, rho
-
-
-def erlang_ls(N, lam, mu, width: int | None = None):
-    """Eq. (4): expected number of requests in the system. +inf when rho >= 1."""
-    log_lq, rho = _erlang_log_lq(N, lam, mu, width)
     a = lam / mu
-    ls = jnp.exp(log_lq) + a
-    return jnp.where(rho < 1.0, ls, jnp.inf)
+    rho = a / N
+    rho_s = jnp.minimum(rho, 1.0 - 1e-9)
+    lq = _erlang_c(N, a, rho_s, width) * rho_s / (1.0 - rho_s)
+    return jnp.where(rho < 1.0, lq + a, jnp.inf)
 
 
 def erlang_ws(N, lam, mu, width: int | None = None):
     """Eq. (7): expected response time per request (Little's law). +inf if unstable.
 
-    Differentiable in ``lam``/``mu``/(continuous) ``N`` on the stable region.
-    ``width`` narrows the masked k-sum (exact for N <= width; see _log_sum_k).
+    Differentiable in ``lam``/``mu`` on the stable region. ``width`` bounds
+    the recurrence (exact for N <= width; see _erlang_c).
     """
     return erlang_ls(N, lam, mu, width) / lam
 
@@ -101,7 +110,7 @@ def erlang_ws_derivs(N, lam, mu, width: int | None = None):
     structured Newton path of the P1 solver (engine._newton_direction_structured).
 
     Uses the Erlang-C identity Lq = C·rho/(1-rho) with C the probability of
-    waiting (log-space, same head/tail forms as ``erlang_ws``), and the exact
+    waiting (the same recurrence as ``erlang_ws``), and the exact
     a-derivatives
 
         dC/da  = C·[(1-rho)/rho + (1-C)/(N(1-rho))]
@@ -121,10 +130,7 @@ def erlang_ws_derivs(N, lam, mu, width: int | None = None):
     rho = a / N
     rho_s = jnp.minimum(rho, 1.0 - 1e-9)
     one_m = 1.0 - rho_s  # (1 - rho), the only small quantity here
-    log_a = jnp.log(lam) - jnp.log(mu)
-    log_head = _log_sum_k(N, log_a, width)
-    log_tail = N * log_a - gammaln(N + 1.0) - jnp.log(one_m)
-    C = jnp.exp(log_tail - jnp.logaddexp(log_head, log_tail))
+    C = _erlang_c(N, a, rho_s, width)
 
     lq = C * rho_s / one_m
     # first derivatives w.r.t. a
@@ -147,20 +153,18 @@ def erlang_ws_derivs(N, lam, mu, width: int | None = None):
 def erlang_wait_prob(N, lam, mu, width: int | None = None):
     """Erlang-C probability of waiting C = P(W_q > 0) (Eq. 5's tail mass).
 
-    Same log-space head/tail forms as ``erlang_ws``; returns 1.0 on the
-    unstable branch (every request waits). Differentiable on the stable
-    region; ``width`` narrows the masked k-sum (exact for N <= width).
+    Same recurrence as ``erlang_ws``; returns 1.0 on the unstable branch
+    (every request waits). Differentiable on the stable region; ``width``
+    bounds the recurrence (exact for N <= width).
     """
     dtype = jnp.result_type(float)
     N = jnp.asarray(N, dtype=dtype)
     lam = jnp.asarray(lam, dtype=dtype)
     mu = jnp.asarray(mu, dtype=dtype)
-    rho = lam / (N * mu)
+    a = lam / mu
+    rho = a / N
     rho_s = jnp.minimum(rho, 1.0 - 1e-9)
-    log_a = jnp.log(lam) - jnp.log(mu)
-    log_head = _log_sum_k(N, log_a, width)
-    log_tail = N * log_a - gammaln(N + 1.0) - jnp.log1p(-rho_s)
-    C = jnp.exp(log_tail - jnp.logaddexp(log_head, log_tail))
+    C = _erlang_c(N, a, rho_s, width)
     return jnp.where(rho < 1.0, C, 1.0)
 
 

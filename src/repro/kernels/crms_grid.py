@@ -18,12 +18,14 @@ seeding (the budget coupling is handled downstream by phase-1 scaling).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 MAX_N = 128  # supported container count in-kernel (edge scenarios: N <= ~40)
+_HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)  # a Python float: stays weakly typed
 
 
 def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
@@ -45,10 +47,16 @@ def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
     rho_s = jnp.minimum(rho, 1.0 - 1e-6)
     log_a = jnp.log(a)
 
+    # lgamma(n+1) via Stirling: the start value, kept only for n >= MAX_N
+    nn = jnp.maximum(n, 1.0)
+    stirling = (nn + 0.5) * jnp.log(nn) - nn + _HALF_LOG_2PI + 1.0 / (12.0 * nn)
+
     # log sum_{k=0}^{N-1} a^k/k! — streaming logsumexp over k as one fori_loop
-    # carry (running max, rescaled running sum, log k!); k=0 term is log 1 = 0
+    # carry (running max, rescaled running sum, log k!); k=0 term is log 1 = 0.
+    # The same carry picks up log n! exactly at k = n: Stirling's series cut
+    # after 1/(12n) is off by ~1e-4 in log n! at n = 3.
     def lse_step(kk, carry):
-        run_max, run_sum, log_fact = carry
+        run_max, run_sum, log_fact, log_nfact = carry
         kf = kk.astype(jnp.float32)
         log_fact = log_fact + jnp.log(kf)
         term = kf * log_a - log_fact
@@ -57,16 +65,16 @@ def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
         run_sum = run_sum * jnp.exp(run_max - new_max) + jnp.where(
             valid, jnp.exp(term - new_max), 0.0
         )
-        return new_max, run_sum, log_fact
+        log_nfact = jnp.where(n == kf, log_fact, log_nfact)
+        return new_max, run_sum, log_fact, log_nfact
 
-    run_max, run_sum, _ = jax.lax.fori_loop(
-        1, MAX_N, lse_step, (jnp.zeros_like(a), jnp.ones_like(a), jnp.zeros_like(a))
+    # int32 bounds: Python-int bounds give an int64 index when x64 is on
+    run_max, run_sum, _, log_nfact = jax.lax.fori_loop(
+        jnp.int32(1), jnp.int32(MAX_N), lse_step,
+        (jnp.zeros_like(a), jnp.ones_like(a), jnp.zeros_like(a), stirling),
     )
     log_head = run_max + jnp.log(run_sum)
 
-    # lgamma(n+1) via Stirling (n >= 1 here; exact enough in f32 for Ws)
-    nn = jnp.maximum(n, 1.0)
-    log_nfact = (nn + 0.5) * jnp.log(nn) - nn + 0.5 * jnp.log(2.0 * jnp.pi) + 1.0 / (12.0 * nn)
     log_tail = n * log_a - log_nfact - jnp.log1p(-rho_s)
     log_pi0 = -jnp.logaddexp(log_head, log_tail)
     log_lq = n * log_a - log_nfact + jnp.log(rho_s) - 2.0 * jnp.log1p(-rho_s) + log_pi0
@@ -81,6 +89,16 @@ def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
         u_ref[...] = jnp.where(mask, util, 1e9)
     else:
         u_ref[...] = jnp.sum(jnp.where(mask, util, 0.0), axis=1, keepdims=True)
+
+
+# Block index maps return int32 explicitly: a Python-int block index becomes
+# int64 when x64 is on, which Mosaic cannot lower.
+def _whole(i):
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _row_tile(i):
+    return i, jnp.int32(0)
 
 
 def crms_grid_eval(kappa, lam, xbar, n, c, m, *, caps_cpu, power_span, alpha, beta,
@@ -120,14 +138,14 @@ def crms_grid_eval(kappa, lam, xbar, n, c, m, *, caps_cpu, power_span, alpha, be
         kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((3, Mp), lambda i: (0, 0)),
-            pl.BlockSpec((1, Mp), lambda i: (0, 0)),
-            pl.BlockSpec((1, Mp), lambda i: (0, 0)),
-            pl.BlockSpec((CB, Mp), lambda i: (i, 0)),
-            pl.BlockSpec((CB, Mp), lambda i: (i, 0)),
-            pl.BlockSpec((CB, Mp), lambda i: (i, 0)),
+            pl.BlockSpec((3, Mp), _whole),
+            pl.BlockSpec((1, Mp), _whole),
+            pl.BlockSpec((1, Mp), _whole),
+            pl.BlockSpec((CB, Mp), _row_tile),
+            pl.BlockSpec((CB, Mp), _row_tile),
+            pl.BlockSpec((CB, Mp), _row_tile),
         ],
-        out_specs=pl.BlockSpec((CB, out_cols), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((CB, out_cols), _row_tile),
         out_shape=jax.ShapeDtypeStruct((nb * CB, out_cols), jnp.float32),
         interpret=interpret,
     )(kpad, lpad, xpad, npad, cpad, mpad)

@@ -18,10 +18,7 @@ from repro.kernels import ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _resolve(backend: str) -> str:

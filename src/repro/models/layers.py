@@ -184,7 +184,7 @@ def apply_attention(
             # the model-sharded T dim makes GSPMD route the whole cache shard
             # through a collective every layer (~0.27 GB/layer observed);
             # instead each T-shard conditionally writes its own slice.
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             data_n = 1
             for ax in batch_sp:
@@ -215,7 +215,7 @@ def apply_attention(
                     P(bsp, None, None, None), P(bsp, None, None, None), P(),
                 ),
                 out_specs=(P(bsp, None, mdl, None), P(bsp, None, mdl, None)),
-                check_rep=False,
+                check_vma=False,
             )(cache["k"], cache["v"], k_new, v_new, cache["index"])
         else:
             k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, cache["index"], axis=2)

@@ -120,7 +120,7 @@ def apply_moe(p, x, cfg: ModelConfig, runtime: Runtime, cf: float = 1.25):
         )
         return y.reshape(B, S, d), aux
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     data_shards = 1
     for ax in batch_sp:
@@ -175,7 +175,7 @@ def apply_moe(p, x, cfg: ModelConfig, runtime: Runtime, cf: float = 1.25):
                 P(mdl, None, None),
             ),
             out_specs=P(batch_sp, None, None),
-            check_rep=False,
+            check_vma=False,
         )(x, ids, pk, p["w_gate"], p["w_up"], p["w_down"])
         return y, aux
 
@@ -224,6 +224,6 @@ def apply_moe(p, x, cfg: ModelConfig, runtime: Runtime, cf: float = 1.25):
             P(mdl, None, None),
         ),
         out_specs=P(tok_sp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x_in, ids, pk, p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

@@ -74,7 +74,7 @@ def compress_allreduce_pod(grads, mesh, error_state, axis: str = "pod"):
 
     Runs inside shard_map over the pod axis with other axes left to GSPMD.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     npods = mesh.shape[axis]
@@ -93,7 +93,7 @@ def compress_allreduce_pod(grads, mesh, error_state, axis: str = "pod"):
 
         red = shard_map(
             reduce_fn, mesh=mesh,
-            in_specs=(P(), P()), out_specs=P(), check_rep=False,
+            in_specs=(P(), P()), out_specs=P(), check_vma=False,
         )(q, scale[None] if scale.ndim == 0 else scale)
         return red, new_err
 

@@ -13,10 +13,10 @@ import pytest
 
 from repro.core.arrivals import ArrivalSpec, ArrivalStream, estimate_arrival, idc_at, mmpp2
 from repro.core.des import FleetSimulator, simulate_mmn
-from repro.core.des_vector import _HAS_JAX, VectorFleetSimulator
+from repro.core.des_vector import VectorFleetSimulator
 from repro.core.queueing import erlang_ws_np
 
-BACKENDS = ("numpy", "jax") if _HAS_JAX else ("numpy",)
+BACKENDS = ("numpy", "jax")
 
 
 def paired_paths(event_sim, vector_sim, name):
@@ -88,8 +88,6 @@ def test_vector_matches_analytic():
 
 
 def test_numpy_backend_matches_jax_backend():
-    if not _HAS_JAX:
-        pytest.skip("jax unavailable; auto IS the numpy backend")
     a = FleetSimulator(seed=3, engine="vector", backend="numpy")
     b = FleetSimulator(seed=3, engine="vector", backend="jax")
     for sim in (a, b):

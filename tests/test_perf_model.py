@@ -75,3 +75,32 @@ def test_all_families_converge():
     fits = fit_best_family(p.cpu, p.mem, p.latency_ms, n_starts=6)
     for name, fr in fits.items():
         assert np.isfinite(fr.rmse), name
+
+
+# The Eq. (1) fit that make_paper_apps(fitted=True) produced with an LU
+# solve in every LM step; the explicit elimination must reproduce it.
+PAPER_KAPPA_LU = (
+    (96.40814101549608, 1.1032735943907883, 0.8963555695572936),
+    (130.6784988842716, 0.9143510293309107, 1.1992692161411553),
+    (23.88768874285883, 1.598601649266485, 0.4534827015550821),
+    (56.089857113863076, 2.800466446017489, 1.3997405411373305),
+)
+
+
+def test_paper_fit_matches_lu_reference():
+    from repro.core.profiler import make_paper_apps
+
+    got = np.array([a.kappa for a in make_paper_apps(fitted=True)])
+    np.testing.assert_allclose(got, np.array(PAPER_KAPPA_LU), rtol=1e-9, atol=0.0)
+
+
+@pytest.mark.parametrize("n", [2, 3, 4])
+def test_solve_small_matches_lu(n):
+    from repro.core.perf_model import _solve_small
+
+    rng = np.random.default_rng(n)
+    J = rng.normal(size=(12, n)) * np.logspace(0, 3, n)  # badly scaled columns
+    A = J.T @ J + 1e-3 * np.eye(n)
+    b = rng.normal(size=n)
+    got = np.asarray(_solve_small(A, b))
+    np.testing.assert_allclose(got, np.linalg.solve(A, b), rtol=1e-10, atol=1e-14)

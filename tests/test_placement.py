@@ -53,7 +53,7 @@ def test_erlang_width_narrowing_is_exact():
     for n, lam, mu in cases:  # scalar per lane, as the vmapped solver calls it
         full = float(queueing.erlang_ws(n, lam, mu))
         narrow = float(queueing.erlang_ws(n, lam, mu, width=16))
-        # masked logsumexp terms are exp(-inf) = 0: bit-exact, not approximate
+        # masked recurrence steps carry B through unchanged: bit-exact
         assert full == narrow
 
 
@@ -282,3 +282,58 @@ def test_fleet_scenario_runner_migration_and_des_sample():
                 assert v["gap_rel"] < 0.6  # short-horizon DES, loose gate
     # the analytic model tracks the DES on average much tighter than per-node
     assert doc["summary"]["validation_gap_rel_mean"] < 0.25
+
+
+# ----------------------------------------------------------------------------
+# The sharded row solve (mesh= path) on four virtual CPU devices
+# ----------------------------------------------------------------------------
+_MESH_PLAN = """
+import json
+import jax
+import numpy as np
+from jax.sharding import Mesh
+from repro.core.placement import FleetPlanner, make_fleet
+
+apps, node_caps = make_fleet(64, 8, seed=5)
+mesh = Mesh(np.array(jax.devices()[:4]), ("nodes",))
+out = {}
+for key, m in (("mesh", mesh), ("single", None)):
+    planner = FleetPlanner(apps, node_caps, alpha=1.4, beta=0.2, mesh=m)
+    cold = planner.plan()
+    drift = planner.replan(lam={apps[3].name: apps[3].lam * 1.1})
+    move = planner.replan(migrations=[(apps[7].name, 40)])
+    out[key] = {
+        "assignment": planner.assignment.tolist(),
+        "sol": np.concatenate([planner.sol_c, planner.sol_m, planner.sol_ws,
+                               planner.node_utility]).tolist(),
+        "utility": [cold.utility, drift.utility, move.utility],
+        "solved": [drift.diagnostics["nodes_solved"], move.diagnostics["nodes_solved"]],
+    }
+out["devices"] = len(jax.devices())
+print(json.dumps(out))
+"""
+
+
+def test_sharded_row_solve_matches_single_device_plan():
+    """FleetPlanner(mesh=...) shard_maps every row solve over a 4-device
+    "nodes" axis — including the 1- and 2-node replan batches, which the
+    planner pads to the axis size — and must reproduce the one-device plan."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    proc = subprocess.run([sys.executable, "-c", _MESH_PLAN], capture_output=True,
+                          text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    sharded, single = out["mesh"], out["single"]
+    assert sharded["assignment"] == single["assignment"]
+    assert sharded["solved"] == single["solved"] == [1, 2]
+    np.testing.assert_allclose(sharded["sol"], single["sol"], rtol=1e-9, atol=0.0)
+    np.testing.assert_allclose(sharded["utility"], single["utility"], rtol=1e-9, atol=0.0)

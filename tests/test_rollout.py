@@ -16,7 +16,6 @@ from repro.core.des import FleetSimulator
 from repro.core.des_vector import (
     _CRN_CACHE,
     _CRN_CACHE_MAX,
-    _HAS_JAX,
     rollout_candidates,
 )
 
@@ -74,7 +73,6 @@ def test_warmup_scores_only_post_warmup_arrivals():
         assert ro.mean_s[0, i] == pytest.approx(float(np.mean(late)), rel=1e-12)
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax unavailable; auto IS numpy")
 def test_numpy_backend_matches_jax_backend():
     mu_all = np.vstack([MU[0], MU[0] * 1.1, MU[0] * 0.95])
     n_all = np.vstack([NS[0], NS[0] + 1, np.maximum(NS[0] - 1, 1)])

@@ -198,3 +198,15 @@ def test_phase1_ok_rows_are_strictly_feasible(M):
         rho = p1_rho(x, packed.jax_dict, n)
         assert np.all(np.asarray(slacks) > 0), b
         assert np.all(np.asarray(rho) < 1.0), b
+
+
+def test_dense_newton_refused_on_tpu(monkeypatch):
+    """The dense Newton's f64 LU solve has no TPU lowering: asking for it on a
+    TPU raises a clear error up front instead of failing in the compiler."""
+    from repro.core.engine import p1_solve_batch
+
+    apps = make_paper_apps(lam=(8.0, 7.0, 10.0, 15.0), fitted=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="dense"):
+        p1_solve_batch(apps, CAPS4, np.array([[7.0, 8.0, 3.0, 7.0]]), 1.4, 0.2,
+                       solver="dense")
