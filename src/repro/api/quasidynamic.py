@@ -73,6 +73,22 @@ class QuasiDynamicPolicy:
         return bool(np.any(drift > self._threshold_for(request)))
 
     def allocate(self, request: AllocRequest) -> AllocResult:
+        """The cached allocation, or a fresh one from the wrapped policy. Under
+        ``jax.profiler`` each call is a span ``repro.decision`` whose ``kind``
+        is skip (cached), warm, cold or raised."""
+        from repro import obs  # lazy: keep api importable sans jax cost
+
+        with obs.span("decision") as span:
+            kind = "raised"
+            try:
+                result = self._allocate(request)
+                diag = result.diagnostics
+                kind = "skip" if diag.cache_hit else "warm" if diag.warm_start else "cold"
+            finally:
+                span.set_metadata(kind=kind)
+        return result
+
+    def _allocate(self, request: AllocRequest) -> AllocResult:
         if not self.should_reoptimize(request):
             return self._result.cached_view()
         names = request.names()

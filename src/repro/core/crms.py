@@ -18,9 +18,11 @@ Solver configuration flows through one frozen ``repro.api.SolverOptions``
 (newton mode, grid seeding, refinement budget, barrier schedule) instead of
 per-call kwargs; the legacy kwargs remain as a thin view that folds into an
 options object. Every solve leaves structured diagnostics (refinement
-iterations, accepted moves, phase-1 rescued/masked rows, warm-vs-cold,
-wall-clock) in ``Allocation.meta["diagnostics"]`` — the API layer lifts them
-into ``AllocResult.diagnostics``.
+iterations, accepted moves, phase-1 rescued/masked rows, warm-vs-cold) in
+``Allocation.meta["diagnostics"]`` — the API layer lifts them into
+``AllocResult.diagnostics`` and times the call. Under ``jax.profiler`` the
+cold path, each refinement iteration and its scoring are host spans
+(``repro.crms.*``, see ``repro.obs``).
 
 Robustness extension beyond the paper (documented in DESIGN.md §8): if P1 is
 infeasible at N* (the paper implicitly assumes it is not), we pre-trim N
@@ -29,11 +31,11 @@ greedily by largest resource footprint until a feasible interior point exists.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.api.types import SolverOptions
 from repro.core import queueing
 from repro.core.batch_eval import evaluate_candidates
@@ -200,7 +202,7 @@ def crms(
     (e.g. the fleet binding packs once per observation epoch).
 
     Structured diagnostics (refinement iterations, accepted moves, phase-1
-    rescued/masked row counts, warm-vs-cold, wall-clock) are recorded in
+    rescued/masked row counts, warm-vs-cold) are recorded in
     ``Allocation.meta["diagnostics"]``.
     """
     if options is None:
@@ -217,7 +219,6 @@ def crms(
     w = options.weight_vector([a.name for a in apps])
     alpha_w = alpha if w is None else alpha * w
     tail_q = float(options.tail_target)
-    t_start = time.perf_counter()
     diag = {
         "warm_start": False,
         "refine_iters": 0,
@@ -274,42 +275,45 @@ def crms(
     diag["warm_start"] = bool(warm_ok)
 
     if not warm_ok:
-        ideal = algorithm1(apps, caps, alpha_w, beta)
-        n = np.array([ic.n for ic in ideal], dtype=int)
-        c = np.array([ic.r_cpu for ic in ideal])
-        m = np.array([ic.r_mem for ic in ideal])
-        c_hint = c.copy()
+        # the cold path: Algorithm 1, then P1 at its counts where they
+        # overrun the caps
+        with obs.span("crms.algorithm1"):
+            ideal = algorithm1(apps, caps, alpha_w, beta)
+            n = np.array([ic.n for ic in ideal], dtype=int)
+            c = np.array([ic.r_cpu for ic in ideal])
+            m = np.array([ic.r_mem for ic in ideal])
+            c_hint = c.copy()
 
-        total_cpu = float(np.sum(n * c))
-        total_mem = float(np.sum(n * m))
-        over = total_cpu > caps.r_cpu or total_mem > caps.r_mem
+            total_cpu = float(np.sum(n * c))
+            total_mem = float(np.sum(n * m))
+            over = total_cpu > caps.r_cpu or total_mem > caps.r_mem
 
-        history.append({"stage": "algorithm1", "n": n.tolist(), "U": None})
+            history.append({"stage": "algorithm1", "n": n.tolist(), "U": None})
 
-        if over:
-            n, ok = _pretrim_n(apps, caps, n, ideal)
-            res = solve_one(n, c_hint)
-            if not res.converged:
-                # fall back: keep trimming until P1 converges
-                for _ in range(int(np.sum(n))):
-                    floors = [max(_stability_floor(a, ch, a.r_max), 1) for a, ch in zip(apps, c_hint)]
-                    cand = np.argsort(-(n * np.array([a.r_min for a in apps])))
-                    moved = False
-                    for i in cand:
-                        if n[i] > floors[i]:
-                            n[i] -= 1
-                            moved = True
+            if over:
+                n, ok = _pretrim_n(apps, caps, n, ideal)
+                res = solve_one(n, c_hint)
+                if not res.converged:
+                    # fall back: keep trimming until P1 converges
+                    for _ in range(int(np.sum(n))):
+                        floors = [max(_stability_floor(a, ch, a.r_max), 1) for a, ch in zip(apps, c_hint)]
+                        cand = np.argsort(-(n * np.array([a.r_min for a in apps])))
+                        moved = False
+                        for i in cand:
+                            if n[i] > floors[i]:
+                                n[i] -= 1
+                                moved = True
+                                break
+                        if not moved:
                             break
-                    if not moved:
-                        break
-                    res = solve_one(n, c_hint)
-                    if res.converged:
-                        break
-            if res.converged:
-                c, m = res.r_cpu, res.r_mem
-            history.append({"stage": "p1_initial", "n": n.tolist(), "U": res.utility})
+                        res = solve_one(n, c_hint)
+                        if res.converged:
+                            break
+                if res.converged:
+                    c, m = res.r_cpu, res.r_mem
+                history.append({"stage": "p1_initial", "n": n.tolist(), "U": res.utility})
 
-        cur = evaluate(apps, n, c, m, caps, alpha, beta, weights=w, tail_q=tail_q)
+            cur = evaluate(apps, n, c, m, caps, alpha, beta, weights=w, tail_q=tail_q)
     else:
         over = True  # warm start implies the constrained regime was entered
 
@@ -350,74 +354,74 @@ def crms(
         if not moves:
             break
         diag["refine_iters"] += 1
-        best = None
-        if solver is not None:
-            for i, delta in moves:
-                n_hat = n.copy()
-                n_hat[i] += delta
-                res = solver(apps, caps, n_hat, alpha_w, beta, c_hint=c_hint)
-                note_p1(res.info)
-                if not res.converged:
-                    continue
-                cand = evaluate(apps, n_hat, res.r_cpu, res.r_mem, caps, alpha, beta, weights=w, tail_q=tail_q)
-                if not (cand.feasible and cand.stable):
-                    continue
-                if best is None or cand.utility < best.utility:
-                    best = cand
-        else:
-            n_cands = np.stack([n + delta * np.eye(M, dtype=int)[i] for i, delta in moves])
-            # the tuned "refine" barrier schedule: ~7x less Newton work per
-            # neighbor at ≤2e-9 relative utility drift (engine.P1_PROFILES).
-            # seed_grid puts grid-argmin hints first; the SP1/warm c_hint and
-            # the waterfill stay in the fallback chain, so seeding never
-            # shrinks the explorable move set
-            batch = p1_solve_batch(
-                packed, caps, n_cands, alpha_w, beta, c_hint=c_hint,
-                profile=options.refine_profile,
-                solver=options.newton, seed_grid=options.grid_seed, tail_q=tail_q,
-            )
-            note_p1(batch.info)
-            if options.rollout_budget > 0 and diag["rollout_calls"] < options.rollout_budget:
-                # DES-scored refinement (DESIGN.md §14): ONE batched CRN
-                # rollout ranks incumbent + all moves on achieved p95 (paired
-                # comparison — shared draws, so short horizons are decisive).
-                # The seed is fixed across iterations, making the rollout
-                # objective a deterministic function of N: accepted moves
-                # strictly decrease it, so the loop cannot cycle.
-                diag["rollout_calls"] += 1
-                picked = _rollout_refine_pick(
-                    apps, caps, packed, w, alpha, beta, tail_q, options,
-                    seed, cur, n, n_cands, batch, rollout_ref_mean,
+        with obs.span("crms.refine", moves=len(moves)) as span:
+            best, stage = None, "greedy"
+            if solver is not None:
+                for i, delta in moves:
+                    n_hat = n.copy()
+                    n_hat[i] += delta
+                    res = solver(apps, caps, n_hat, alpha_w, beta, c_hint=c_hint)
+                    note_p1(res.info)
+                    if not res.converged:
+                        continue
+                    cand = evaluate(apps, n_hat, res.r_cpu, res.r_mem, caps, alpha, beta, weights=w, tail_q=tail_q)
+                    if not (cand.feasible and cand.stable):
+                        continue
+                    if best is None or cand.utility < best.utility:
+                        best = cand
+            else:
+                n_cands = np.stack([n + delta * np.eye(M, dtype=int)[i] for i, delta in moves])
+                # the tuned "refine" barrier schedule: ~7x less Newton work per
+                # neighbor at ≤2e-9 relative utility drift (engine.P1_PROFILES).
+                # seed_grid puts grid-argmin hints first; the SP1/warm c_hint and
+                # the waterfill stay in the fallback chain, so seeding never
+                # shrinks the explorable move set
+                batch = p1_solve_batch(
+                    packed, caps, n_cands, alpha_w, beta, c_hint=c_hint,
+                    profile=options.refine_profile,
+                    solver=options.newton, seed_grid=options.grid_seed, tail_q=tail_q,
                 )
-                if picked is None:
-                    break  # incumbent wins every paired comparison
-                cur = picked
-                n = picked.n.copy()
-                diag["accepted_moves"] += 1
-                diag["rollout_accepted"] += 1
-                history.append(
-                    {"stage": "greedy_rollout", "n": n.tolist(), "U": picked.utility}
-                )
-                continue
-            u_cand, _, _ = evaluate_candidates(
-                packed, caps, n_cands.astype(float), batch.r_cpu, batch.r_mem,
-                alpha_w, beta, hard=True, tail_q=tail_q,
+                note_p1(batch.info)
+                if options.rollout_budget > 0 and diag["rollout_calls"] < options.rollout_budget:
+                    # DES-scored refinement (DESIGN.md §14): ONE batched CRN
+                    # rollout ranks incumbent + all moves on achieved p95 (paired
+                    # comparison — shared draws, so short horizons are decisive).
+                    # The seed is fixed across iterations, making the rollout
+                    # objective a deterministic function of N: accepted moves
+                    # strictly decrease it, so the loop cannot cycle. A picked
+                    # move is accepted as it is; None: the incumbent wins every
+                    # paired comparison.
+                    diag["rollout_calls"] += 1
+                    stage = "greedy_rollout"
+                    best = _rollout_refine_pick(
+                        apps, caps, packed, w, alpha, beta, tail_q, options,
+                        seed, cur, n, n_cands, batch, rollout_ref_mean,
+                    )
+                else:
+                    with obs.span("crms.score"):
+                        u_cand, _, _ = evaluate_candidates(
+                            packed, caps, n_cands.astype(float), batch.r_cpu, batch.r_mem,
+                            alpha_w, beta, hard=True, tail_q=tail_q,
+                        )
+                        u_cand = np.where(batch.converged, u_cand, np.inf)
+                        for j in np.argsort(u_cand):
+                            if not np.isfinite(u_cand[j]) or u_cand[j] >= cur.utility - 1e-12:
+                                break
+                            cand = evaluate(apps, n_cands[j], batch.r_cpu[j], batch.r_mem[j], caps, alpha, beta, weights=w, tail_q=tail_q)
+                            if cand.feasible and cand.stable:
+                                best = cand
+                                break
+            accepted = best is not None and (
+                stage == "greedy_rollout" or best.utility < cur.utility - 1e-12
             )
-            u_cand = np.where(batch.converged, u_cand, np.inf)
-            for j in np.argsort(u_cand):
-                if not np.isfinite(u_cand[j]) or u_cand[j] >= cur.utility - 1e-12:
-                    break
-                cand = evaluate(apps, n_cands[j], batch.r_cpu[j], batch.r_mem[j], caps, alpha, beta, weights=w, tail_q=tail_q)
-                if cand.feasible and cand.stable:
-                    best = cand
-                    break
-        if best is not None and best.utility < cur.utility - 1e-12:
-            cur = best
-            n = best.n.copy()
-            diag["accepted_moves"] += 1
-            history.append({"stage": "greedy", "n": n.tolist(), "U": best.utility})
-        else:
+            span.set_metadata(accepted=int(accepted))
+        if not accepted:
             break
+        cur = best
+        n = best.n.copy()
+        diag["accepted_moves"] += 1
+        diag["rollout_accepted"] += int(stage == "greedy_rollout")
+        history.append({"stage": stage, "n": n.tolist(), "U": best.utility})
 
     # If the sufficient-resource config was feasible from the start, Algorithm 2
     # still applies P1 once over the fixed N* to tighten quotas under the caps.
@@ -433,7 +437,6 @@ def crms(
     cur.meta["history"] = history
     if ideal is not None:
         cur.meta["ideal"] = [dataclasses.asdict(ic) for ic in ideal]
-    diag["wall_clock_s"] = time.perf_counter() - t_start
     cur.meta["diagnostics"] = diag
     return cur
 

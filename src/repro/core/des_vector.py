@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.arrivals import ArrivalStream, parse_arrival, validate_service
 from repro.core.des import (
     _CHUNK,
@@ -125,6 +126,7 @@ def _segment_scan_numpy(W0, smask, gaps, svcs, valid):
 
 @jax.jit
 def _segment_scan_jax(W0, smask, gaps, svcs, valid):
+    obs.retraced("segment_scan", shape=obs.shape(*gaps.shape, W0.shape[1]))
     j = jnp.arange(W0.shape[1])[None, :]
 
     def step(W, xs):
@@ -149,11 +151,15 @@ def _segment_scan_jax(W0, smask, gaps, svcs, valid):
 
 def segment_scan(W0, smask, gaps, svcs, valid, backend="jax"):
     """Run the batched recurrence over one segment: the jitted ``lax.scan``,
-    or the chunked NumPy loop when ``backend="numpy"``."""
+    or the chunked NumPy loop when ``backend="numpy"``. The jitted call
+    (the inputs' copy to the device and the launch) and the wait for its
+    outputs are the spans ``repro.des.dispatch`` and ``repro.des.fetch``."""
     _check_backend(backend)
     if backend == "jax":
-        Wf, waits = _segment_scan_jax(W0, smask, gaps, svcs, valid)
-        return np.asarray(Wf), np.asarray(waits)
+        with obs.span("des.dispatch"):
+            Wf, waits = _segment_scan_jax(W0, smask, gaps, svcs, valid)
+        with obs.span("des.fetch"):
+            return np.asarray(Wf), np.asarray(waits)
     return _segment_scan_numpy(W0, smask, gaps, svcs, valid)
 
 
@@ -820,64 +826,81 @@ class VectorFleetSimulator(FleetSimulator):
     def _simulate_segment(self, t_end: float, drain: bool) -> float:
         """Advance every cluster from the current clock to t_end (one
         stationary segment) through one batched scan. Returns the time of the
-        last completion (for drain's clock semantics)."""
-        t0 = self.t
-        work = []
-        for cl in self._clusters.values():
-            arr = cl.arrivals_until(t_end)
-            svc = cl.services(arr.shape[0])
-            nq = cl.queue_t.shape[0]
-            # replayed queued customers go first (FCFS), at effective time t0
-            eff = np.concatenate((np.full(nq, t0), arr))
-            tru = np.concatenate((cl.queue_t, arr))
-            s = np.concatenate((cl.queue_s, svc))
-            work.append((cl, eff, tru, s))
-        K = max((e.shape[0] for _, e, _, _ in work), default=0)
-        if K == 0:
-            return t0
-        Kp = _pad_pow2(K)
-        Mp = _pad_pow2(len(work))
-        n_pad = _pad_pow2(max(max(cl.n_up for cl, *_ in work), 1))
+        last completion (for drain's clock semantics).
 
-        W0 = np.full((Mp, n_pad), _BIG)
-        smask = np.zeros((Mp, n_pad), dtype=bool)
-        gaps = np.zeros((Kp, Mp))
-        svcs = np.zeros((Kp, Mp))
-        valid = np.zeros((Kp, Mp), dtype=bool)
-        for i, (cl, eff, _, s) in enumerate(work):
-            W0[i] = cl.workload_at(t0, n_pad)
-            smask[i, : cl.n_up] = True
-            k = eff.shape[0]
-            gaps[:k, i] = np.diff(eff, prepend=t0)
-            svcs[:k, i] = s
-            valid[:k, i] = True
+        Under ``jax.profiler`` this is the span ``repro.des.segment``, with
+        the draws, the packing of the padded arrays and the hand-off as
+        spans inside it, and as stats the customers whose service started
+        (``customers``), the scan's real and padded steps (``steps_used``,
+        ``steps``), lanes (``lanes``, ``lanes_padded``) and server slots
+        (``servers_padded``)."""
+        with obs.span("des.segment") as span:
+            t0 = self.t
+            work = []
+            with obs.span("des.draw"):
+                for cl in self._clusters.values():
+                    arr = cl.arrivals_until(t_end)
+                    svc = cl.services(arr.shape[0])
+                    nq = cl.queue_t.shape[0]
+                    # replayed queued customers go first (FCFS), at effective time t0
+                    eff = np.concatenate((np.full(nq, t0), arr))
+                    tru = np.concatenate((cl.queue_t, arr))
+                    s = np.concatenate((cl.queue_s, svc))
+                    work.append((cl, eff, tru, s))
+            K = max((e.shape[0] for _, e, _, _ in work), default=0)
+            if K == 0:
+                span.set_metadata(customers=0, steps_used=0, steps=0)
+                return t0
+            Kp = _pad_pow2(K)
+            Mp = _pad_pow2(len(work))
+            n_pad = _pad_pow2(max(max(cl.n_up for cl, *_ in work), 1))
 
-        _, waits = segment_scan(W0, smask, gaps, svcs, valid, backend=self.backend)
+            with obs.span("des.pack"):
+                W0 = np.full((Mp, n_pad), _BIG)
+                smask = np.zeros((Mp, n_pad), dtype=bool)
+                gaps = np.zeros((Kp, Mp))
+                svcs = np.zeros((Kp, Mp))
+                valid = np.zeros((Kp, Mp), dtype=bool)
+                for i, (cl, eff, _, s) in enumerate(work):
+                    W0[i] = cl.workload_at(t0, n_pad)
+                    smask[i, : cl.n_up] = True
+                    k = eff.shape[0]
+                    gaps[:k, i] = np.diff(eff, prepend=t0)
+                    svcs[:k, i] = s
+                    valid[:k, i] = True
 
-        t_last = t0
-        for i, (cl, eff, tru, s) in enumerate(work):
-            if drain and cl.inflight.shape[0]:
-                t_last = max(t_last, float(cl.inflight.max()))
-            k = eff.shape[0]
-            if k == 0:
-                cl.inflight = cl.inflight[cl.inflight > t_end]
-                continue
-            start = eff + waits[:k, i]
-            comp = start + s
-            # wait >= the sentinel means "no server will ever free" (n=0):
-            # those customers stay queued even through drain, as in the oracle
-            can_start = waits[:k, i] < 0.5 * _BIG
-            started = can_start if drain else can_start & (start <= t_end)
-            cl.record(tru[started], (start - tru)[started], s[started])
-            cl.queue_t = tru[~started]
-            cl.queue_s = s[~started]
-            done = comp[started]
-            cl.inflight = np.concatenate(
-                (cl.inflight[cl.inflight > t_end], done[done > t_end])
-            )
-            if done.shape[0]:
-                t_last = max(t_last, float(done.max()))
-        return t_last
+            _, waits = segment_scan(W0, smask, gaps, svcs, valid, backend=self.backend)
+
+            t_last = t0
+            customers = 0
+            with obs.span("des.record"):
+                for i, (cl, eff, tru, s) in enumerate(work):
+                    if drain and cl.inflight.shape[0]:
+                        t_last = max(t_last, float(cl.inflight.max()))
+                    k = eff.shape[0]
+                    if k == 0:
+                        cl.inflight = cl.inflight[cl.inflight > t_end]
+                        continue
+                    start = eff + waits[:k, i]
+                    comp = start + s
+                    # wait >= the sentinel means "no server will ever free" (n=0):
+                    # those customers stay queued even through drain, as in the oracle
+                    can_start = waits[:k, i] < 0.5 * _BIG
+                    started = can_start if drain else can_start & (start <= t_end)
+                    t_rec = tru[started]
+                    cl.record(t_rec, (start - tru)[started], s[started])
+                    customers += t_rec.shape[0]
+                    cl.queue_t = tru[~started]
+                    cl.queue_s = s[~started]
+                    done = comp[started]
+                    cl.inflight = np.concatenate(
+                        (cl.inflight[cl.inflight > t_end], done[done > t_end])
+                    )
+                    if done.shape[0]:
+                        t_last = max(t_last, float(done.max()))
+            span.set_metadata(customers=customers, steps_used=K, steps=Kp, lanes=len(work),
+                              lanes_padded=Mp, servers_padded=n_pad)
+            return t_last
 
     # ------------------------------------------------------------------ stats
     def snapshot(self, name: str) -> tuple[float, float]:

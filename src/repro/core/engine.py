@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import queueing
 from repro.core.perf_model import eq1_latency
 from repro.core.problem import App, ServerCaps
@@ -466,6 +467,7 @@ def _ip_solve_batched(
     """One jitted vmap over a (B, 2M) batch of starts + (B, M) counts. Returns
     (x* (B, 2M), utility (B,)) — the utility is the tail objective when
     ``tail_q`` is set, so candidate ranking and the reported optimum agree."""
+    obs.retraced("ip_solve", shape=obs.shape(*x0.shape))
 
     def one(x0_i, n_i):
         x = _ip_core(x0_i, packed, n_i, caps_cpu, caps_mem, power_span, alpha, beta,
@@ -949,6 +951,9 @@ def p1_solve_batch(
     returns ok=False rows with ``info["binding"]`` naming the constraint;
     ``"raise"`` raises a structured ``InfeasibleAllocation`` carrying it —
     no caller has to pattern-match a silent all-False ``started`` vector.
+    Under ``jax.profiler`` the call is the span ``repro.p1.solve`` (stats
+    ``rows``, ``profile``, ``padded``, ``rescued``, ``masked``) holding
+    ``repro.p1.grid_seed``, ``.phase1``, ``.dispatch`` and ``.fetch``.
     """
     if on_infeasible not in ("mask", "raise"):
         raise ValueError(
@@ -969,85 +974,94 @@ def p1_solve_batch(
             "no longer be exact"
         )
     B, M = n_np.shape
-    # Phase-1 hint chain: grid-seeded cells first (when enabled), then the
-    # caller's hint (SP1 ideal / warm quotas), then the plain waterfill.
-    # Hints are advisory — rows where a hinted phase-1 fails (e.g. a
-    # budget-oblivious hint starves a CPU-hungry app) retry down the chain,
-    # so adding a hint source can only ever ADD feasible rows, and each
-    # retry touches only the still-failing row subset.
-    hint_chain: list = [c_hint] if c_hint is not None else []
-    if seed_grid:
-        hint_chain.insert(0, grid_seed_chints(packed, caps, n_np, alpha, beta))
-    if not hint_chain or hint_chain[-1] is not None:
-        hint_chain.append(None)
-    x0, ok = find_feasible_start_batch(packed, caps, n_np, c_hint=hint_chain[0])
-    n_rescued = 0  # rows the hint fallback chain recovered after a failed start
-    for fb in hint_chain[1:]:
-        if np.all(ok):
-            break
-        idx = np.where(~ok)[0]
-        fb_np = np.asarray(fb, dtype=float) if fb is not None else None
-        sub = fb_np[idx] if fb_np is not None and fb_np.ndim == 2 else fb_np
-        x0_fb, ok_fb = find_feasible_start_batch(packed, caps, n_np[idx], c_hint=sub)
-        x0[idx[ok_fb]] = x0_fb[ok_fb]
-        ok[idx[ok_fb]] = True
-        n_rescued += int(np.sum(ok_fb))
+    with obs.span("p1.solve", rows=B, profile=profile) as span:
+        # Phase-1 hint chain: grid-seeded cells first (when enabled), then the
+        # caller's hint (SP1 ideal / warm quotas), then the plain waterfill.
+        # Hints are advisory — rows where a hinted phase-1 fails (e.g. a
+        # budget-oblivious hint starves a CPU-hungry app) retry down the chain,
+        # so adding a hint source can only ever ADD feasible rows, and each
+        # retry touches only the still-failing row subset.
+        hint_chain: list = [c_hint] if c_hint is not None else []
+        if seed_grid:
+            with obs.span("p1.grid_seed"):
+                hint_chain.insert(0, grid_seed_chints(packed, caps, n_np, alpha, beta))
+        if not hint_chain or hint_chain[-1] is not None:
+            hint_chain.append(None)
+        with obs.span("p1.phase1"):
+            x0, ok = find_feasible_start_batch(packed, caps, n_np, c_hint=hint_chain[0])
+            n_rescued = 0  # rows the hint fallback chain recovered after a failed start
+            for fb in hint_chain[1:]:
+                if np.all(ok):
+                    break
+                idx = np.where(~ok)[0]
+                fb_np = np.asarray(fb, dtype=float) if fb is not None else None
+                sub = fb_np[idx] if fb_np is not None and fb_np.ndim == 2 else fb_np
+                x0_fb, ok_fb = find_feasible_start_batch(packed, caps, n_np[idx], c_hint=sub)
+                x0[idx[ok_fb]] = x0_fb[ok_fb]
+                ok[idx[ok_fb]] = True
+                n_rescued += int(np.sum(ok_fb))
 
-    r_cpu = np.zeros((B, M))
-    r_mem = np.broadcast_to(packed.r_min, (B, M)).copy()
-    utility = np.full(B, np.inf)
-    converged = np.zeros(B, dtype=bool)
-    if not np.any(ok):
-        binding, bind_counts = _diagnose_infeasible(packed, caps, n_np)
-        if on_infeasible == "raise":
-            raise InfeasibleAllocation(
-                binding, {"batch": B, "rows_by_binding": bind_counts}
+        r_cpu = np.zeros((B, M))
+        r_mem = np.broadcast_to(packed.r_min, (B, M)).copy()
+        utility = np.full(B, np.inf)
+        converged = np.zeros(B, dtype=bool)
+        if not np.any(ok):
+            span.set_metadata(padded=0, rescued=n_rescued, masked=B)
+            binding, bind_counts = _diagnose_infeasible(packed, caps, n_np)
+            if on_infeasible == "raise":
+                raise InfeasibleAllocation(
+                    binding, {"batch": B, "rows_by_binding": bind_counts}
+                )
+            return P1BatchResult(
+                r_cpu, r_mem, utility, converged, started=ok,
+                info={"n_feasible_start": 0, "n_rescued": n_rescued, "n_masked": B,
+                      "binding": binding, "rows_by_binding": bind_counts},
             )
+
+        sub = int(np.argmax(ok))  # donor row for masked-out lanes
+        x0 = np.where(ok[:, None], x0, x0[sub])
+        n_solve = np.where(ok[:, None], n_np, n_np[sub])
+        Bp = _pad_pow2(B) if pad else B
+        if Bp > B:
+            x0 = np.concatenate([x0, np.broadcast_to(x0[sub], (Bp - B, 2 * M))], axis=0)
+            n_solve = np.concatenate([n_solve, np.broadcast_to(n_solve[sub], (Bp - B, M))], axis=0)
+
+        # dispatch returns before the device is done; the fetch waits for it
+        with obs.span("p1.dispatch"):
+            x, u = _ip_solve_batched(
+                jnp.asarray(x0),
+                packed.as_dict(),
+                jnp.asarray(n_solve),
+                jnp.asarray(float(caps.r_cpu)),
+                jnp.asarray(float(caps.r_mem)),
+                jnp.asarray(float(caps.power.span)),
+                _alpha_arg(alpha),
+                float(beta),
+                n_outer=n_outer,
+                n_inner=n_inner,
+                solver=solver,
+                width=max_servers,
+                tail_q=float(tail_q),
+            )
+        with obs.span("p1.fetch"):
+            x = np.asarray(x)[:B]
+            u = np.asarray(u)[:B]
+        r_cpu = np.where(ok[:, None], x[:, :M], r_cpu)
+        r_mem = np.where(ok[:, None], x[:, M:], r_mem)
+        utility = np.where(ok, u, np.inf)
+        converged = ok & np.isfinite(utility)
+        n_masked = int(B - ok.sum())
+        span.set_metadata(padded=Bp, rescued=n_rescued, masked=n_masked)
         return P1BatchResult(
             r_cpu, r_mem, utility, converged, started=ok,
-            info={"n_feasible_start": 0, "n_rescued": n_rescued, "n_masked": B,
-                  "binding": binding, "rows_by_binding": bind_counts},
+            info={
+                "n_feasible_start": int(ok.sum()),
+                "n_rescued": n_rescued,
+                "n_masked": n_masked,
+                "batch": B,
+                "padded_to": Bp,
+            },
         )
-
-    sub = int(np.argmax(ok))  # donor row for masked-out lanes
-    x0 = np.where(ok[:, None], x0, x0[sub])
-    n_solve = np.where(ok[:, None], n_np, n_np[sub])
-    Bp = _pad_pow2(B) if pad else B
-    if Bp > B:
-        x0 = np.concatenate([x0, np.broadcast_to(x0[sub], (Bp - B, 2 * M))], axis=0)
-        n_solve = np.concatenate([n_solve, np.broadcast_to(n_solve[sub], (Bp - B, M))], axis=0)
-
-    x, u = _ip_solve_batched(
-        jnp.asarray(x0),
-        packed.as_dict(),
-        jnp.asarray(n_solve),
-        jnp.asarray(float(caps.r_cpu)),
-        jnp.asarray(float(caps.r_mem)),
-        jnp.asarray(float(caps.power.span)),
-        _alpha_arg(alpha),
-        float(beta),
-        n_outer=n_outer,
-        n_inner=n_inner,
-        solver=solver,
-        width=max_servers,
-        tail_q=float(tail_q),
-    )
-    x = np.asarray(x)[:B]
-    u = np.asarray(u)[:B]
-    r_cpu = np.where(ok[:, None], x[:, :M], r_cpu)
-    r_mem = np.where(ok[:, None], x[:, M:], r_mem)
-    utility = np.where(ok, u, np.inf)
-    converged = ok & np.isfinite(utility)
-    return P1BatchResult(
-        r_cpu, r_mem, utility, converged, started=ok,
-        info={
-            "n_feasible_start": int(ok.sum()),
-            "n_rescued": n_rescued,
-            "n_masked": int(B - ok.sum()),
-            "batch": B,
-            "padded_to": Bp,
-        },
-    )
 
 
 # ----------------------------------------------------------------------------

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
+
 MAX_N = 128  # supported container count in-kernel (edge scenarios: N <= ~40)
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)  # a Python float: stays weakly typed
 
@@ -31,6 +33,7 @@ _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)  # a Python float: stays weakly ty
 def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
                  caps_cpu: float, power_span: float, alpha: float, beta: float,
                  n_apps: int, per_app: bool):
+    obs.retraced("crms_grid", shape=obs.shape(*n_ref.shape))  # the block
     k1 = kappa_ref[0, :]
     k2 = kappa_ref[1, :]
     k3 = kappa_ref[2, :]
