@@ -341,9 +341,8 @@ def _ip_core(x0, packed, n, caps_cpu, caps_mem, power_span, alpha, beta, n_outer
     testing (tests/test_structured_newton.py pins the two within 1e-6).
 
     ``width`` (static) narrows every Erlang-C recurrence from MAX_SERVERS
-    steps to the given width — exact whenever all container counts stay
-    below it (queueing._erlang_c), and the dominant term in fleet-scale wall
-    clock."""
+    steps to the given width — exact whenever no container count exceeds it
+    (queueing._erlang_c), and the dominant term in a solve's device time."""
 
     def strictly_feasible(x):
         _, slacks = p1_barrier(x, 1.0, packed, n, caps_cpu, caps_mem, power_span, alpha, beta,
@@ -467,7 +466,8 @@ def _ip_solve_batched(
     """One jitted vmap over a (B, 2M) batch of starts + (B, M) counts. Returns
     (x* (B, 2M), utility (B,)) — the utility is the tail objective when
     ``tail_q`` is set, so candidate ranking and the reported optimum agree."""
-    obs.retraced("ip_solve", shape=obs.shape(*x0.shape))
+    obs.retraced("ip_solve", shape=obs.shape(*x0.shape),
+                 width=queueing.MAX_SERVERS if width is None else width)
 
     def one(x0_i, n_i):
         x = _ip_core(x0_i, packed, n_i, caps_cpu, caps_mem, power_span, alpha, beta,
@@ -906,6 +906,14 @@ def _diagnose_infeasible(packed, caps: ServerCaps, n_np: np.ndarray) -> tuple:
 # well inside the exchange loop's move-acceptance margins.
 P1_PROFILES = {"reference": (14, 24), "refine": (12, 4), "fleet": (8, 3)}
 
+# Floor of the Erlang-C width p1_solve_batch derives when no max_servers is
+# given (the pow2 ceiling of the largest count solved). Each recurrence step is
+# a divide on a Newton step's sequential chain, so the width sets most of a
+# small solve's device time; the floor keeps one compiled program per (rows,
+# schedule) for every node whose counts stay <= 16, both paper nodes and their
+# ±1 refinement moves among them.
+P1_MIN_WIDTH = 16
+
 
 def p1_solve_batch(
     apps,
@@ -939,12 +947,16 @@ def p1_solve_batch(
     the coarse per-app (c, m) utility grid sweep (grid_seed_chints) at the
     head of the hint chain; rows where a hinted phase-1 fails fall back to
     the caller's ``c_hint`` and finally the plain waterfill, so hint sources
-    only ever add feasible rows. ``max_servers`` narrows every Erlang-C
-    recurrence from queueing.MAX_SERVERS to the given static width — EXACT
-    (not approximate) because every count in the batch must stay ≤ it, which
-    is validated eagerly; callers should pass a pow2 so distinct fleets share
-    one jit cache entry. ``tail_q`` (static) swaps the per-app latency term
-    for the analytic quantile surrogate (see p1_objective); the phase-1
+    only ever add feasible rows. Every Erlang-C recurrence runs a static
+    width: ``max_servers`` when given (every count in the batch must stay ≤
+    it, which is validated eagerly; callers should pass a pow2 so distinct
+    fleets share one jit cache entry), else the pow2 ceiling of the largest
+    count solved, never below P1_MIN_WIDTH. Either is EXACT (not
+    approximate): masked steps past a row's count carry the recurrence
+    through unchanged. The derived width grows with the counts, so counts
+    above queueing.MAX_SERVERS get a wide enough recurrence too.
+    ``tail_q`` (static) swaps the per-app latency term for the analytic
+    quantile surrogate (see p1_objective); the phase-1
     start and grid seeding stay mean-based — they are advisory hints, and
     the tail surrogate shares the mean's feasible region. ``on_infeasible``
     names the all-masked-batch behavior: ``"mask"`` (default, back-compat)
@@ -952,7 +964,8 @@ def p1_solve_batch(
     ``"raise"`` raises a structured ``InfeasibleAllocation`` carrying it —
     no caller has to pattern-match a silent all-False ``started`` vector.
     Under ``jax.profiler`` the call is the span ``repro.p1.solve`` (stats
-    ``rows``, ``profile``, ``padded``, ``rescued``, ``masked``) holding
+    ``rows``, ``profile``, ``padded``, ``width`` (0 when no row is solved),
+    ``rescued``, ``masked``) holding
     ``repro.p1.grid_seed``, ``.phase1``, ``.dispatch`` and ``.fetch``.
     """
     if on_infeasible not in ("mask", "raise"):
@@ -1006,7 +1019,7 @@ def p1_solve_batch(
         utility = np.full(B, np.inf)
         converged = np.zeros(B, dtype=bool)
         if not np.any(ok):
-            span.set_metadata(padded=0, rescued=n_rescued, masked=B)
+            span.set_metadata(padded=0, width=0, rescued=n_rescued, masked=B)
             binding, bind_counts = _diagnose_infeasible(packed, caps, n_np)
             if on_infeasible == "raise":
                 raise InfeasibleAllocation(
@@ -1021,6 +1034,8 @@ def p1_solve_batch(
         sub = int(np.argmax(ok))  # donor row for masked-out lanes
         x0 = np.where(ok[:, None], x0, x0[sub])
         n_solve = np.where(ok[:, None], n_np, n_np[sub])
+        width = (max_servers if max_servers is not None
+                 else max(P1_MIN_WIDTH, _pad_pow2(int(np.ceil(n_solve.max())))))
         Bp = _pad_pow2(B) if pad else B
         if Bp > B:
             x0 = np.concatenate([x0, np.broadcast_to(x0[sub], (Bp - B, 2 * M))], axis=0)
@@ -1040,7 +1055,7 @@ def p1_solve_batch(
                 n_outer=n_outer,
                 n_inner=n_inner,
                 solver=solver,
-                width=max_servers,
+                width=width,
                 tail_q=float(tail_q),
             )
         with obs.span("p1.fetch"):
@@ -1051,7 +1066,7 @@ def p1_solve_batch(
         utility = np.where(ok, u, np.inf)
         converged = ok & np.isfinite(utility)
         n_masked = int(B - ok.sum())
-        span.set_metadata(padded=Bp, rescued=n_rescued, masked=n_masked)
+        span.set_metadata(padded=Bp, width=width, rescued=n_rescued, masked=n_masked)
         return P1BatchResult(
             r_cpu, r_mem, utility, converged, started=ok,
             info={

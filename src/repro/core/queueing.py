@@ -41,10 +41,11 @@ def _erlang_c(N, a, rho_s, width: int | None = None):
 
     The recurrence runs ``width`` steps (MAX_SERVERS by default) and masks
     every step k > N, so it is EXACT, not an approximation, whenever
-    N <= width: masked steps carry B through unchanged. The fleet placement
-    layer passes the pow2 ceiling of its largest container count (~16
-    instead of 512) — every Erlang evaluation in the interior point pays
-    this width.
+    N <= width: masked steps carry B through unchanged. Every interior-point
+    solve passes a static width: ``engine.p1_solve_batch`` the pow2 ceiling
+    of its batch's largest count (at least 16), the fleet placement layer its
+    own sticky pow2 width — every Erlang evaluation in the interior point
+    pays this width, one sequential step each.
     """
     ks = jnp.arange(1, (MAX_SERVERS if width is None else width) + 1, dtype=a.dtype)
 

@@ -90,6 +90,47 @@ def test_refine_profile_matches_reference():
     np.testing.assert_allclose(fast.utility[conv], ref.utility[conv], rtol=1e-6)
 
 
+# Both paper nodes (caps, λ) and counts CRMS settles on there; "count_17"
+# moves around a row with 16 containers, so one move reaches 17 (width 32).
+PAPER_NODES = {
+    "paper_node": (ServerCaps(30.0, 10.0), (8, 7, 10, 15), [6, 7, 3, 7]),
+    "paper_sufficient": (ServerCaps(120.0, 40.0), (6, 6, 6, 6), [6, 8, 3, 5]),
+}
+
+
+def _moves(n0):
+    M = len(n0)
+    return np.stack([np.asarray(n0) + d * np.eye(M, dtype=int)[i]
+                     for i in range(M) for d in (-1, +1)]).astype(float)
+
+
+@pytest.mark.parametrize("node", PAPER_NODES)
+@pytest.mark.parametrize("case,tail_q", [
+    ("reference_row", 0.0), ("refine_moves", 0.0),
+    ("reference_row", 0.95), ("refine_moves", 0.95), ("count_17", 0.0),
+])
+def test_derived_erlang_width_matches_full_width(node, case, tail_q):
+    """The width p1_solve_batch derives from its counts (16 or 32 here)
+    leaves every result bit-identical to the full MAX_SERVERS = 512 width:
+    masked recurrence steps are identities."""
+    caps, lam, n0 = PAPER_NODES[node]
+    apps = make_paper_apps(lam=lam, fitted=False)
+    if case == "reference_row":
+        n_batch, profile = np.asarray([n0], dtype=float), "reference"
+    elif case == "refine_moves":
+        n_batch, profile = _moves(n0), "refine"
+    else:
+        n_batch, profile = _moves([n0[0], 16, *n0[2:]]), "refine"
+    derived = p1_solve_batch(apps, caps, n_batch, 1.4, 0.2, profile=profile, tail_q=tail_q)
+    full = p1_solve_batch(apps, caps, n_batch, 1.4, 0.2, profile=profile, tail_q=tail_q,
+                          max_servers=512)
+    assert derived.converged.any()
+    if case == "count_17":
+        assert derived.converged[n_batch[:, 1] == 17].all()
+    for key in ("r_cpu", "r_mem", "utility", "converged"):
+        np.testing.assert_array_equal(getattr(derived, key), getattr(full, key))
+
+
 def test_feasible_start_batch_masks_infeasible_rows():
     n_batch = np.asarray([[6, 7, 3, 7], [80, 80, 80, 80]], dtype=float)
     x0, ok = find_feasible_start_batch(APPS, CAPS, n_batch)

@@ -6,6 +6,7 @@ import os
 
 import jax
 import numpy as np
+import pytest
 from jax.profiler import ProfileData, TraceAnnotation
 
 from repro import obs
@@ -88,6 +89,7 @@ def test_crms_decisions_record_nested_spans_with_exact_counters(tmp_path):
     solves_in_refine = 0
     for solve in _named(spans, "p1.solve"):
         assert solve["stats"]["padded"] == 1 << (solve["stats"]["rows"] - 1).bit_length()
+        assert solve["stats"]["width"] == engine.P1_MIN_WIDTH  # every count here is <= 16
         refine = [r for r in refines if r["start"] <= solve["start"] and solve["end"] <= r["end"]]
         if refine:  # a refinement batch holds every move of its iteration
             assert solve["stats"]["rows"] == refine[0]["stats"]["moves"]
@@ -162,6 +164,26 @@ def test_retrace_markers_fire_on_a_new_shape_only(tmp_path):
     assert [s["stats"]["shape"] for s in _named(spans, "retrace.crms_grid")] == ["16x8"] * 2
     np.testing.assert_array_equal(first[0].r_cpu, second[0].r_cpu)
     np.testing.assert_array_equal(first[1][1], second[1][1])
+
+
+@pytest.mark.parametrize("top,max_servers,width", [
+    (10, None, 16),  # below the floor: the floor
+    (17, None, 32),  # the pow2 ceiling of the largest count
+    (10, 37, 37),  # a caller's width as it is given
+])
+def test_p1_solve_records_its_erlang_width(tmp_path, top, max_servers, width):
+    apps = make_paper_apps(lam=LAM, fitted=False)
+    # five rows, unpadded: a shape no other solve uses, so the call traces anew
+    n = np.array([[6, 7, 3, 7], [6, top, 3, 7], [6, 8, 3, 7], [5, 7, 3, 7], [6, 7, 4, 7]],
+                 dtype=float)
+    with jax.profiler.trace(str(tmp_path)):
+        res = engine.p1_solve_batch(apps, CAPS, n, 1.4, 0.2, pad=False, profile="refine",
+                                    max_servers=max_servers)
+    spans = _read(tmp_path)
+
+    assert res.converged[1]  # the row holding the largest count is solved
+    assert [s["stats"]["width"] for s in _named(spans, "p1.solve")] == [width]
+    assert [s["stats"]["width"] for s in _named(spans, "retrace.ip_solve")] == [width]
 
 
 def test_spans_are_inert_without_a_profiler(tmp_path):

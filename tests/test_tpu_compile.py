@@ -79,6 +79,20 @@ def test_p1_structured_batch_compiles(one_chip):
     assert compiled.as_text()
 
 
+def test_p1_single_row_compiles_at_the_derived_width(one_chip):
+    """The re-plan's single-row P1 of a paper node, at the Erlang width
+    p1_solve_batch derives for counts <= 16."""
+    M = 4
+    n_outer, n_inner = engine.P1_PROFILES["reference"]
+    scalar = _spec(one_chip)
+    compiled = engine._ip_solve_batched.lower(
+        _spec(one_chip, 1, 2 * M), _packed(one_chip, M), _spec(one_chip, 1, M),
+        scalar, scalar, scalar, 1.4, 0.2, n_outer=n_outer, n_inner=n_inner,
+        width=engine.P1_MIN_WIDTH,
+    ).compile()
+    assert compiled.as_text()
+
+
 def test_ip_solve_rows_compiles(one_chip):
     """The region planner's row solve: 1024 nodes × 16 app slots."""
     N, M = 1024, 16
