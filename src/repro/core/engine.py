@@ -502,6 +502,8 @@ def _rows_core(x0, packed_rows, n, caps_cpu, caps_mem, power_span, alpha, beta,
     shared packing, many count vectors), every row here carries its own
     packed-field stack AND its own (caps_cpu, caps_mem) budget — one row per
     fleet node. Returns (x* (N, 2M), utility (N,), ws (N, M))."""
+    obs.retraced("ip_solve_rows", shape=obs.shape(*x0.shape),
+                 width=queueing.MAX_SERVERS if width is None else width)
 
     def one(x0_i, packed_i, n_i, ccpu_i, cmem_i):
         x = _ip_core(x0_i, packed_i, n_i, ccpu_i, cmem_i, power_span, alpha, beta,
@@ -915,6 +917,14 @@ P1_PROFILES = {"reference": (14, 24), "refine": (12, 4), "fleet": (8, 3)}
 P1_MIN_WIDTH = 16
 
 
+def erlang_width(n_max) -> int:
+    """The static Erlang-C width for container counts up to ``n_max``: the
+    pow2 ceiling of ``n_max``, never below P1_MIN_WIDTH. Exact (masked steps
+    past a count carry the recurrence through unchanged); the one rule of
+    ``p1_solve_batch`` and the fleet planner."""
+    return max(P1_MIN_WIDTH, _pad_pow2(int(np.ceil(n_max))))
+
+
 def p1_solve_batch(
     apps,
     caps: ServerCaps,
@@ -1034,8 +1044,7 @@ def p1_solve_batch(
         sub = int(np.argmax(ok))  # donor row for masked-out lanes
         x0 = np.where(ok[:, None], x0, x0[sub])
         n_solve = np.where(ok[:, None], n_np, n_np[sub])
-        width = (max_servers if max_servers is not None
-                 else max(P1_MIN_WIDTH, _pad_pow2(int(np.ceil(n_solve.max())))))
+        width = max_servers if max_servers is not None else erlang_width(n_solve.max())
         Bp = _pad_pow2(B) if pad else B
         if Bp > B:
             x0 = np.concatenate([x0, np.broadcast_to(x0[sub], (Bp - B, 2 * M))], axis=0)

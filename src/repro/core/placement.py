@@ -19,15 +19,30 @@ pow2 sentinel padding (node axis)
     freezes sentinel coordinates — padded rows match standalone solves to
     fp precision (tests/test_placement.py).
 narrow Erlang width
-    Every Erlang-C recurrence is narrowed from queueing.MAX_SERVERS (512)
-    steps to the pow2 ceiling of the fleet's largest container count —
-    EXACT, and the dominant wall-clock lever on the interior point.
+    Every Erlang-C recurrence runs the width ``engine.erlang_width`` derives
+    from the fleet's largest container count (the pow2 ceiling, at least
+    ``P1_MIN_WIDTH``), the rule ``p1_solve_batch`` uses — EXACT, and the
+    dominant wall-clock lever on the interior point. The planner's width
+    only grows, so rates wiggling around a pow2 boundary keep one program.
 incremental re-scoring
     ``replan`` re-solves ONLY the nodes touched by a λ change or migration,
     warm-hinted from the current solution; untouched nodes keep their
     allocations verbatim. Invariant: a node's inner solution depends only on
     its own app set and budgets, so the untouched rows are exactly what a
     cold solve would reproduce (no exchange runs during incremental plans).
+
+A node phase-1 cannot start gets its container counts grown before it is
+masked and offloaded (``_repair_counts``): counts follow each app's ideal
+configuration, sized for a node of its own, and by Eq. (1) more, smaller
+containers serve more requests per core.
+
+Under ``jax.profiler`` a cold plan is the span ``repro.region.plan`` and an
+incremental one ``repro.region.replan`` (stats ``nodes_solved``,
+``migrations``, ``emergency``, ``nodes_failed``); each row-batch solve is
+``repro.region.solve`` (``rows``, ``padded``, ``width``, ``M_pad``,
+``rescued``, ``repaired``, ``masked``) holding ``.phase1``, ``.dispatch``
+and ``.fetch``; the cold plan's exchange refinement is
+``repro.region.exchange`` (``accepted``).
 """
 from __future__ import annotations
 
@@ -38,13 +53,13 @@ from typing import Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import queueing
 from repro.core.engine import (
     P1_PROFILES,
     PackedApps,
-    _eq1_np,
     _pad_pow2,
-    as_packed,
+    erlang_width,
     find_feasible_start_batch,
     ideal_configs_batch,
     ip_solve_rows,
@@ -60,6 +75,19 @@ _SENTINEL = dict(
 )
 
 _ACCEPT_TOL = 1e-9  # exchange move acceptance margin (sum of pair utilities)
+
+# a plan's counters, over its row-batch solves: rows the phase-1 hint chain
+# rescued, rows whose counts were grown to start (_repair_counts), rows masked,
+# and rows solved and dispatched (solved plus pow2 padding)
+_COUNTERS = {"p1_rescued_rows": 0, "rows_repaired": 0, "p1_masked_rows": 0,
+             "rows_solved": 0, "rows_dispatched": 0}
+
+# the PackedApps fields each node's row carries
+_FIELDS = ("kappa", "lam", "xbar", "r_min", "r_max", "cpu_min", "cpu_max")
+
+# count repair: rounds, and the growth of every count of a row per round
+_REPAIR_ROUNDS = 3
+_REPAIR_GROWTH = 1.25
 
 
 @dataclasses.dataclass
@@ -201,7 +229,7 @@ class FleetPlanner:
         self._last_hint = np.full(self.A, np.nan)  # phase-1 hint actually used
         self.node_utility = np.full(self.N, np.inf)
         self.node_ok = np.zeros(self.N, dtype=bool)
-        self._counters = {"p1_rescued_rows": 0, "p1_masked_rows": 0}
+        self._counters = dict(_COUNTERS)
 
     # ------------------------------------------------------------------
     # placement construction
@@ -272,11 +300,9 @@ class FleetPlanner:
                 break
 
     def _erlang_width(self) -> int:
-        w = _pad_pow2(max(int(self.n.max()) + 1, 8))
         # sticky: only grow, so λ wiggles around a pow2 boundary don't thrash
         # the jit cache
-        prev = getattr(self, "_width", 0)
-        return min(max(w, prev), queueing.MAX_SERVERS)
+        return max(erlang_width(self.n.max()), getattr(self, "_width", 0))
 
     # ------------------------------------------------------------------
     # row building + batched solve
@@ -321,6 +347,46 @@ class FleetPlanner:
         n_rows = np.where(mask > 0, self.n[safe], 0).astype(float)
         return slots, mask, rows, n_rows
 
+    def _waterfill(self, sub, idx, pp, n, mask):
+        """Phase-1 with the plain waterfill (no hint) on the rows ``idx`` of
+        the batch of nodes ``sub``, at counts ``n``."""
+        return find_feasible_start_batch(
+            PackedApps(**{k: getattr(pp, k)[idx] for k in _FIELDS}),
+            ServerCaps(self.caps_cpu[sub][idx], self.caps_mem[sub][idx]),
+            n, mask=mask[idx],
+        )
+
+    def _repair_counts(self, sub, slots, mask, pp, n_rows, x0, ok) -> int:
+        """Grow the container counts of the rows phase-1 could not start, and
+        start them again. Counts follow the apps' ideal configurations, sized
+        for a node of their own, so an app whose load needs most of a node's
+        CPU can get too few containers for the CPU it gets: by Eq. (1) more,
+        smaller containers serve more requests per core. Each round grows
+        every count of a failing row by a quarter while its memory floor
+        fits; a row that starts keeps its grown counts (in ``n_rows``,
+        ``x0``, ``ok`` and the planner's counts). Returns the rows repaired."""
+        work = n_rows.copy()
+        repaired = 0
+        for _ in range(_REPAIR_ROUNDS):
+            idx = np.where(~ok)[0]
+            grown = np.ceil(work[idx] * _REPAIR_GROWTH)
+            fits = (np.sum(grown * pp.r_min[idx], axis=1)
+                    <= 0.97 * self.caps_mem[sub][idx])
+            idx, grown = idx[fits], grown[fits]
+            if idx.size == 0:
+                break
+            work[idx] = grown
+            x0_r, ok_r = self._waterfill(sub, idx, pp, grown, mask)
+            done = idx[ok_r]
+            x0[done] = x0_r[ok_r]
+            ok[done] = True
+            n_rows[done] = grown[ok_r]
+            live = mask[done] > 0
+            self.n[slots[done][live]] = n_rows[done][live].astype(int)
+            self._last_hint[slots[done][live]] = np.nan
+            repaired += done.size
+        return repaired
+
     def _solve_nodes(self, nodes) -> dict:
         """Re-solve the given nodes' inner P1 problems in one row batch and
         scatter the results into the per-app solution state. Returns counter
@@ -329,75 +395,79 @@ class FleetPlanner:
         sub = np.asarray(sorted(set(int(j) for j in nodes)))
         if sub.size == 0:
             return {"rows": 0, "rescued": 0, "masked": 0}
-        slots, mask, rows, n_rows = self._build_rows(sub)
-
-        pp = PackedApps(**{k: rows[k] for k in (
-            "kappa", "lam", "xbar", "r_min", "r_max", "cpu_min", "cpu_max")})
-        caps = ServerCaps(self.caps_cpu[sub], self.caps_mem[sub])
-        # warm hint: current per-app quotas where solved, ideal c* otherwise
-        hint_app = np.where(self.sol_c > 0, self.sol_c, self.c_star)
-        c_hint = np.where(mask > 0, hint_app[np.where(slots >= 0, slots, 0)], 1.0)
-        x0, ok = find_feasible_start_batch(pp, caps, n_rows, c_hint=c_hint, mask=mask)
-        live_slots = slots[mask > 0]
-        self._last_hint[live_slots] = c_hint[mask > 0]
-        rescued = 0
-        if not ok.all():  # fall back to the plain waterfill, failing rows only
-            idx = np.where(~ok)[0]
-            self._last_hint[slots[idx][mask[idx] > 0]] = np.nan
-            x0_fb, ok_fb = find_feasible_start_batch(
-                PackedApps(**{k: getattr(pp, k)[idx] for k in (
-                    "kappa", "lam", "xbar", "r_min", "r_max", "cpu_min", "cpu_max")}),
-                ServerCaps(self.caps_cpu[sub][idx], self.caps_mem[sub][idx]),
-                n_rows[idx], mask=mask[idx],
-            )
-            x0[idx[ok_fb]] = x0_fb[ok_fb]
-            ok[idx[ok_fb]] = True
-            rescued = int(ok_fb.sum())
-
         B = sub.size
-        Bp = _pad_pow2(B)
-        if self.mesh is not None:  # the row batch must split evenly over the mesh
-            k = self.mesh.shape[self.mesh_axis]
-            Bp = -(-Bp // k) * k
-        self._width = self._erlang_width()
+        with obs.span("region.solve", rows=B) as span:
+            slots, mask, rows, n_rows = self._build_rows(sub)
+            pp = PackedApps(**{k: rows[k] for k in _FIELDS})
+            caps = ServerCaps(self.caps_cpu[sub], self.caps_mem[sub])
+            # warm hint: current per-app quotas where solved, ideal c* otherwise
+            hint_app = np.where(self.sol_c > 0, self.sol_c, self.c_star)
+            c_hint = np.where(mask > 0, hint_app[np.where(slots >= 0, slots, 0)], 1.0)
+            with obs.span("region.phase1"):
+                x0, ok = find_feasible_start_batch(pp, caps, n_rows, c_hint=c_hint, mask=mask)
+                live_slots = slots[mask > 0]
+                self._last_hint[live_slots] = c_hint[mask > 0]
+                rescued = 0
+                if not ok.all():  # fall back to the plain waterfill, failing rows only
+                    idx = np.where(~ok)[0]
+                    self._last_hint[slots[idx][mask[idx] > 0]] = np.nan
+                    x0_fb, ok_fb = self._waterfill(sub, idx, pp, n_rows[idx], mask)
+                    x0[idx[ok_fb]] = x0_fb[ok_fb]
+                    ok[idx[ok_fb]] = True
+                    rescued = int(ok_fb.sum())
+                repaired = self._repair_counts(sub, slots, mask, pp, n_rows, x0, ok)
 
-        def pad(a):
-            if Bp == B:
-                return a
-            return np.concatenate([a, np.broadcast_to(a[:1], (Bp - B,) + a.shape[1:])], 0)
+            Bp = _pad_pow2(B)
+            if self.mesh is not None:  # the row batch must split evenly over the mesh
+                k = self.mesh.shape[self.mesh_axis]
+                Bp = -(-Bp // k) * k
+            self._width = self._erlang_width()
 
-        packed_rows = {k: jnp.asarray(pad(v)) for k, v in rows.items()}
-        packed_rows["mask"] = jnp.asarray(pad(mask))
-        x, u, ws = ip_solve_rows(
-            jnp.asarray(pad(x0)),
-            packed_rows,
-            jnp.asarray(pad(n_rows)),
-            jnp.asarray(pad(self.caps_cpu[sub])),
-            jnp.asarray(pad(self.caps_mem[sub])),
-            jnp.asarray(self.power_span),
-            self.alpha,
-            self.beta,
-            n_outer=self.n_outer,
-            n_inner=self.n_inner,
-            width=self._width,
-            mesh=self.mesh,
-            mesh_axis=self.mesh_axis,
-        )
-        x = np.asarray(x)[:B]
-        u = np.asarray(u)[:B]
-        ws = np.asarray(ws)[:B]
+            def pad(a):
+                if Bp == B:
+                    return a
+                return np.concatenate([a, np.broadcast_to(a[:1], (Bp - B,) + a.shape[1:])], 0)
 
-        solved = ok & np.isfinite(u)
-        self.node_utility[sub] = np.where(solved, u, np.inf)
-        self.node_ok[sub] = solved
-        live = (mask > 0) & solved[:, None]
-        app_idx = slots[live]
-        self.sol_c[app_idx] = x[:, : self.M_pad][live]
-        self.sol_m[app_idx] = x[:, self.M_pad:][live]
-        self.sol_ws[app_idx] = ws[live]
-        masked = int(B - ok.sum())
-        self._counters["p1_rescued_rows"] += rescued
-        self._counters["p1_masked_rows"] += masked
+            # dispatch returns before the device is done; the fetch waits for it
+            with obs.span("region.dispatch"):
+                packed_rows = {k: jnp.asarray(pad(v)) for k, v in rows.items()}
+                packed_rows["mask"] = jnp.asarray(pad(mask))
+                x, u, ws = ip_solve_rows(
+                    jnp.asarray(pad(x0)),
+                    packed_rows,
+                    jnp.asarray(pad(n_rows)),
+                    jnp.asarray(pad(self.caps_cpu[sub])),
+                    jnp.asarray(pad(self.caps_mem[sub])),
+                    jnp.asarray(self.power_span),
+                    self.alpha,
+                    self.beta,
+                    n_outer=self.n_outer,
+                    n_inner=self.n_inner,
+                    width=self._width,
+                    mesh=self.mesh,
+                    mesh_axis=self.mesh_axis,
+                )
+            with obs.span("region.fetch"):
+                x = np.asarray(x)[:B]
+                u = np.asarray(u)[:B]
+                ws = np.asarray(ws)[:B]
+
+            solved = ok & np.isfinite(u)
+            self.node_utility[sub] = np.where(solved, u, np.inf)
+            self.node_ok[sub] = solved
+            live = (mask > 0) & solved[:, None]
+            app_idx = slots[live]
+            self.sol_c[app_idx] = x[:, : self.M_pad][live]
+            self.sol_m[app_idx] = x[:, self.M_pad:][live]
+            self.sol_ws[app_idx] = ws[live]
+            masked = int(B - ok.sum())
+            self._counters["p1_rescued_rows"] += rescued
+            self._counters["p1_masked_rows"] += masked
+            self._counters["rows_solved"] += B
+            self._counters["rows_dispatched"] += Bp
+            self._counters["rows_repaired"] += repaired
+            span.set_metadata(padded=Bp, width=self._width, M_pad=self.M_pad,
+                              rescued=rescued, repaired=repaired, masked=masked)
         return {"rows": B, "rescued": rescued, "masked": masked}
 
     # ------------------------------------------------------------------
@@ -520,11 +590,19 @@ class FleetPlanner:
         """Cold plan: greedy assignment (already built), one full row-batch
         solve over all N nodes, then exchange refinement."""
         t0 = time.perf_counter()
-        self._counters = {"p1_rescued_rows": 0, "p1_masked_rows": 0}
-        self._solve_nodes(range(self.N))
-        accepted = self._exchange() if self.exchange_rounds > 0 else 0
-        return self._finish(t0, cold=True, nodes_solved=self.N,
-                            migrations=0, exchange_accepted=accepted)
+        self._counters = dict(_COUNTERS)
+        with obs.span("region.plan", nodes_solved=self.N, migrations=0,
+                      emergency=0) as span:
+            self._solve_nodes(range(self.N))
+            accepted = 0
+            if self.exchange_rounds > 0:
+                with obs.span("region.exchange") as ex:
+                    accepted = self._exchange()
+                    ex.set_metadata(accepted=accepted)
+            plan = self._finish(t0, cold=True, nodes_solved=self.N,
+                                migrations=0, exchange_accepted=accepted)
+            span.set_metadata(nodes_failed=plan.diagnostics["nodes_failed"])
+        return plan
 
     def replan(self, lam=None, migrations=()) -> FleetPlan:
         """Incremental re-plan: update λ and/or apply migrations, re-solve
@@ -535,93 +613,100 @@ class FleetPlanner:
         deficit-covering migrations (smallest sufficient app first) to
         max-headroom nodes, re-solving each (source, destination) pair."""
         t0 = time.perf_counter()
-        self._counters = {"p1_rescued_rows": 0, "p1_masked_rows": 0}
-        touched: set = set()
-        n_migrations = 0
-        if lam is not None:
-            lam_map = (
-                lam if isinstance(lam, dict)
-                else {self.names[i]: float(v) for i, v in enumerate(np.asarray(lam))}
-            )
-            for name, v in lam_map.items():
+        self._counters = dict(_COUNTERS)
+        with obs.span("region.replan") as span:
+            touched: set = set()
+            n_migrations = 0
+            if lam is not None:
+                lam_map = (
+                    lam if isinstance(lam, dict)
+                    else {self.names[i]: float(v) for i, v in enumerate(np.asarray(lam))}
+                )
+                for name, v in lam_map.items():
+                    i = self._name_idx[name]
+                    if float(v) == self.lam[i]:
+                        continue
+                    self.lam[i] = float(v)
+                    floor = queueing.stability_lower_bound(self.lam[i], self.mu_star[i])
+                    self.floors[i] = floor
+                    scaled = int(round(self.n_star[i] * self.lam[i] / self.lam_ref[i]))
+                    self.n[i] = min(max(scaled, floor), queueing.MAX_SERVERS - 1)
+                    touched.add(int(self.assignment[i]))
+            counts = np.bincount(self.assignment, minlength=self.N)
+            for name, dst in migrations:
                 i = self._name_idx[name]
-                if float(v) == self.lam[i]:
+                src, dst = int(self.assignment[i]), int(dst)
+                if src == dst:
                     continue
-                self.lam[i] = float(v)
-                floor = queueing.stability_lower_bound(self.lam[i], self.mu_star[i])
-                self.floors[i] = floor
-                scaled = int(round(self.n_star[i] * self.lam[i] / self.lam_ref[i]))
-                self.n[i] = min(max(scaled, floor), queueing.MAX_SERVERS - 1)
-                touched.add(int(self.assignment[i]))
-        counts = np.bincount(self.assignment, minlength=self.N)
-        for name, dst in migrations:
-            i = self._name_idx[name]
-            src, dst = int(self.assignment[i]), int(dst)
-            if src == dst:
-                continue
-            if counts[dst] >= self.M_pad:
-                raise ValueError(
-                    f"migration of {name!r} to node {dst} exceeds M_pad={self.M_pad}"
-                )
-            self.assignment[i] = dst
-            counts[src] -= 1
-            counts[dst] += 1
-            touched.update((src, dst))
-            n_migrations += 1
-        self._pretrim_counts(touched)
-        self._solve_nodes(touched)
-        # Emergency offload for touched nodes that lost feasibility —
-        # container-granular: estimate the binding resource's deficit from
-        # the per-app container footprints (n_i containers of quota c_i/m_i
-        # each) and move the SMALLEST app whose departure covers it, not
-        # blindly the largest-footprint one — the donor node keeps as much
-        # of its working set as possible. When no single app covers the
-        # deficit the largest goes first and the loop continues, spilling to
-        # max-headroom destinations (possibly a different one per move),
-        # bounded at 3 moves per node so a hopeless node cannot stall the
-        # re-plan.
-        bad = [j for j in touched if not self.node_ok[j]]
-        for j in bad:
-            for _ in range(3):
-                if self.node_ok[j]:
-                    break
-                on_j = np.where(self.assignment == j)[0]
-                if on_j.size <= 1:
-                    break
-                cpu_foot = self.n[on_j] * np.maximum(
-                    self.sol_c[on_j], self.c_star[on_j]
-                )
-                mem_foot = self.n[on_j] * np.maximum(
-                    self.m_star[on_j], self.packed.r_min[on_j]
-                )
-                cpu_def = float(cpu_foot.sum() - self.caps_cpu[j])
-                # 0.97: the interior point needs strict slack (_pretrim_counts)
-                mem_def = float(mem_foot.sum() - 0.97 * self.caps_mem[j])
-                if mem_def / self.caps_mem[j] >= cpu_def / self.caps_cpu[j]:
-                    foot, deficit = mem_foot, mem_def
-                else:
-                    foot, deficit = cpu_foot, cpu_def
-                covering = np.where(foot >= deficit)[0] if deficit > 0 else []
-                a = (
-                    int(on_j[covering[np.argmin(foot[covering])]])
-                    if len(covering)
-                    else int(on_j[np.argmax(foot)])
-                )
-                head = self._headroom()
-                head[j] = -np.inf
-                cand = [
-                    d for d in np.argsort(-head) if counts[int(d)] + 1 < self.M_pad
-                ]
-                if not cand:
-                    break
-                d = int(cand[0])
-                self.assignment[a] = d
-                counts[j] -= 1
-                counts[d] += 1
+                if counts[dst] >= self.M_pad:
+                    raise ValueError(
+                        f"migration of {name!r} to node {dst} exceeds M_pad={self.M_pad}"
+                    )
+                self.assignment[i] = dst
+                counts[src] -= 1
+                counts[dst] += 1
+                touched.update((src, dst))
                 n_migrations += 1
-                self._solve_nodes([j, d])
-        return self._finish(t0, cold=False, nodes_solved=len(touched),
-                            migrations=n_migrations, exchange_accepted=0)
+            self._pretrim_counts(touched)
+            self._solve_nodes(touched)
+            # Emergency offload for touched nodes that lost feasibility —
+            # container-granular: estimate the binding resource's deficit from
+            # the per-app container footprints (n_i containers of quota c_i/m_i
+            # each) and move the SMALLEST app whose departure covers it, not
+            # blindly the largest-footprint one — the donor node keeps as much
+            # of its working set as possible. When no single app covers the
+            # deficit the largest goes first and the loop continues, spilling to
+            # max-headroom destinations (possibly a different one per move),
+            # bounded at 3 moves per node so a hopeless node cannot stall the
+            # re-plan.
+            bad = [j for j in touched if not self.node_ok[j]]
+            emergency = 0
+            for j in bad:
+                for _ in range(3):
+                    if self.node_ok[j]:
+                        break
+                    on_j = np.where(self.assignment == j)[0]
+                    if on_j.size <= 1:
+                        break
+                    cpu_foot = self.n[on_j] * np.maximum(
+                        self.sol_c[on_j], self.c_star[on_j]
+                    )
+                    mem_foot = self.n[on_j] * np.maximum(
+                        self.m_star[on_j], self.packed.r_min[on_j]
+                    )
+                    cpu_def = float(cpu_foot.sum() - self.caps_cpu[j])
+                    # 0.97: the interior point needs strict slack (_pretrim_counts)
+                    mem_def = float(mem_foot.sum() - 0.97 * self.caps_mem[j])
+                    if mem_def / self.caps_mem[j] >= cpu_def / self.caps_cpu[j]:
+                        foot, deficit = mem_foot, mem_def
+                    else:
+                        foot, deficit = cpu_foot, cpu_def
+                    covering = np.where(foot >= deficit)[0] if deficit > 0 else []
+                    a = (
+                        int(on_j[covering[np.argmin(foot[covering])]])
+                        if len(covering)
+                        else int(on_j[np.argmax(foot)])
+                    )
+                    head = self._headroom()
+                    head[j] = -np.inf
+                    cand = [
+                        d for d in np.argsort(-head) if counts[int(d)] + 1 < self.M_pad
+                    ]
+                    if not cand:
+                        break
+                    d = int(cand[0])
+                    self.assignment[a] = d
+                    counts[j] -= 1
+                    counts[d] += 1
+                    n_migrations += 1
+                    emergency += 1
+                    self._solve_nodes([j, d])
+            plan = self._finish(t0, cold=False, nodes_solved=len(touched),
+                                migrations=n_migrations, exchange_accepted=0)
+            span.set_metadata(nodes_solved=len(touched), migrations=n_migrations,
+                              emergency=emergency,
+                              nodes_failed=plan.diagnostics["nodes_failed"])
+        return plan
 
     def _finish(self, t0, **extra) -> FleetPlan:
         util = float(np.sum(np.where(self.node_ok, self.node_utility, 0.0)))

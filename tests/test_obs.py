@@ -195,3 +195,51 @@ def test_spans_are_inert_without_a_profiler(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         assert TraceAnnotation.is_enabled()
     assert not TraceAnnotation.is_enabled()
+
+
+def test_region_plans_record_spans_with_exact_counters(tmp_path):
+    from repro.core.placement import FleetPlanner, make_fleet
+
+    apps, node_caps = make_fleet(5, 3, seed=21)
+    # the "refine" schedule: a row-solve program no other planner compiles,
+    # so the first solve of each batch size here traces anew
+    planner = FleetPlanner(apps, node_caps, alpha=1.4, beta=0.2, profile="refine")
+    with jax.profiler.trace(str(tmp_path)):
+        plans = [planner.plan(),
+                 planner.replan(lam={apps[0].name: apps[0].lam * 1.2}),
+                 planner.replan(lam={apps[0].name: apps[0].lam * 1.3})]
+        src = int(planner.assignment[1])
+        plans.append(planner.replan(migrations=[(apps[1].name, (src + 1) % 5)]))
+    spans = _read(tmp_path)
+
+    outer = _named(spans, "region.plan") + _named(spans, "region.replan")
+    assert [s["name"] for s in outer] == ["region.plan"] + ["region.replan"] * 3
+    for span, plan in zip(outer, plans):
+        diag = plan.diagnostics
+        for key in ("nodes_solved", "migrations", "nodes_failed"):
+            assert span["stats"][key] == diag[key]
+        assert span["stats"]["emergency"] == 0
+        solves = [s for s in _named(spans, "region.solve")
+                  if span["start"] <= s["start"] and s["end"] <= span["end"]]
+        assert sum(s["stats"]["rows"] for s in solves) == diag["rows_solved"]
+        assert sum(s["stats"]["padded"] for s in solves) == diag["rows_dispatched"]
+    assert [p.diagnostics["nodes_solved"] for p in plans] == [5, 1, 1, 2]
+    (exchange,) = _named(spans, "region.exchange")
+    _parent(exchange, spans, "region.plan")
+    assert exchange["stats"]["accepted"] == plans[0].diagnostics["exchange_accepted"]
+
+    solves = _named(spans, "region.solve")
+    for s in solves:
+        st = s["stats"]
+        assert st["padded"] == 1 << (st["rows"] - 1).bit_length()
+        assert (st["width"], st["M_pad"]) == (planner._width, planner.M_pad)
+        assert st["masked"] == st["rescued"] == st["repaired"] == 0
+    for name in ("region.phase1", "region.dispatch", "region.fetch"):
+        inner = _named(spans, name)
+        assert len(inner) == len(solves)
+        for e in inner:
+            _parent(e, spans, "region.solve")
+    retraced = [(s["stats"]["shape"], s["stats"]["width"])
+                for s in _named(spans, "retrace.ip_solve_rows")]
+    assert sorted(retraced) == sorted(
+        {(f"{s['stats']['padded']}x{2 * planner.M_pad}", planner._width) for s in solves})

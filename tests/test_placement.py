@@ -30,9 +30,10 @@ from repro.api import (
 )
 from repro.api.scenario import AppJoin, AppLeave, LambdaSet
 from repro.core import queueing
-from repro.core.engine import PackedApps, p1_solve_batch
+from repro.core.engine import PackedApps, erlang_width, p1_solve_batch
 from repro.core.placement import FleetPlanner, make_fleet
 from repro.core.problem import App, ServerCaps
+from repro.core.profiler import make_paper_apps
 
 ALPHA, BETA = 1.4, 0.2
 
@@ -55,6 +56,46 @@ def test_erlang_width_narrowing_is_exact():
         narrow = float(queueing.erlang_ws(n, lam, mu, width=16))
         # masked recurrence steps carry B through unchanged: bit-exact
         assert full == narrow
+
+
+@pytest.mark.parametrize("n_max,width", [(1, 16), (16, 16), (17, 32), (24.0, 32), (511, 512)])
+def test_erlang_width_rule(n_max, width):
+    assert erlang_width(n_max) == width
+
+
+def test_planner_width_is_the_engine_rule_and_only_grows():
+    apps, node_caps = make_fleet(4, 3, seed=2)
+    planner = FleetPlanner(apps, node_caps, alpha=ALPHA, beta=BETA, exchange_rounds=0)
+    planner.plan()
+    assert planner._width == erlang_width(planner.n.max()) == 16
+    planner.replan(lam={apps[0].name: apps[0].lam * 12.0})
+    grown = planner._width
+    assert grown == erlang_width(planner.n.max()) > 16
+    planner.replan(lam={apps[0].name: apps[0].lam})
+    assert planner._width == grown > erlang_width(planner.n.max())
+
+
+def test_count_repair_starts_a_node_held_by_one_hot_app():
+    """An app whose load needs most of a node's CPU gets its counts from an
+    ideal configuration sized for a node of its own: too few containers for
+    phase-1 to start. The planner grows them until it does, and the row then
+    matches the node's standalone solve at the grown counts."""
+    hot, cool = make_paper_apps(lam=(7.0, 33.5, 6.0, 6.0), fitted=False)[1:3]
+    hot = dataclasses.replace(hot, name="hot")
+    planner = FleetPlanner([hot, cool], [ServerCaps(120.0, 40.0)] * 2, alpha=ALPHA,
+                           beta=BETA, exchange_rounds=0, initial_assignment=[0, 1])
+    n_before = planner.n.copy()
+    plan = planner.plan()
+    assert plan.diagnostics["rows_repaired"] == 1
+    assert plan.diagnostics["nodes_failed"] == 0
+    assert planner.n[0] > n_before[0] and planner.n[1] == n_before[1]
+    on_j, n_apps, caps, n_row, c_hint = planner.node_problem(0)
+    assert c_hint is None  # the repaired row started from the plain waterfill
+    ref = p1_solve_batch(PackedApps.from_apps(n_apps), caps, n_row, ALPHA, BETA,
+                         profile=planner.profile, max_servers=planner._width)
+    assert bool(ref.converged[0])
+    np.testing.assert_allclose(ref.r_cpu[0], planner.sol_c[on_j], rtol=1e-6)
+    np.testing.assert_allclose(ref.r_mem[0], planner.sol_m[on_j], rtol=1e-6)
 
 
 def test_width_below_counts_rejected():
