@@ -14,6 +14,13 @@ compile time). f32 throughout (the oracle runs f64; tests bound the drift).
 ``reduce`` selects the output: "sum" (B,) totals Eq. (8) over apps;
 "per_app" (B, M) keeps each app's utility term — the argmin input for grid
 seeding (the budget coupling is handled downstream by phase-1 scaling).
+
+The ``pallas_call`` is built once per static signature (tiles, lane-padded
+apps, output mode) and reused: ``caps_cpu``, ``power_span``, ``alpha`` and
+``beta`` reach the kernel as one float32 SMEM operand, not as constants of
+its closure, so a later call at a seen shape traces, lowers and compiles
+nothing. Called eagerly, the kernel is its own device program
+(``jit_wrapped``); under an outer ``jax.jit`` it inlines into that program.
 """
 from __future__ import annotations
 
@@ -22,7 +29,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 
@@ -30,10 +39,10 @@ MAX_N = 128  # supported container count in-kernel (edge scenarios: N <= ~40)
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)  # a Python float: stays weakly typed
 
 
-def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
-                 caps_cpu: float, power_span: float, alpha: float, beta: float,
+def _crms_kernel(s_ref, kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
                  n_apps: int, per_app: bool):
     obs.retraced("crms_grid", shape=obs.shape(*n_ref.shape))  # the block
+    caps_cpu, power_span, alpha, beta = s_ref[0], s_ref[1], s_ref[2], s_ref[3]
     k1 = kappa_ref[0, :]
     k2 = kappa_ref[1, :]
     k3 = kappa_ref[2, :]
@@ -96,12 +105,47 @@ def _crms_kernel(kappa_ref, lam_ref, xbar_ref, n_ref, c_ref, m_ref, u_ref, *,
 
 # Block index maps return int32 explicitly: a Python-int block index becomes
 # int64 when x64 is on, which Mosaic cannot lower.
+def _scalars(i):
+    return (jnp.int32(0),)
+
+
 def _whole(i):
     return jnp.int32(0), jnp.int32(0)
 
 
 def _row_tile(i):
     return i, jnp.int32(0)
+
+
+@functools.lru_cache(maxsize=32)
+def _grid_call(nb: int, CB: int, Mp: int, n_apps: int, per_app: bool, interpret: bool):
+    """The ``pallas_call`` for one static signature. Its jitted wrapper keeps
+    the compiled program, so every later call at these shapes reuses it."""
+    out_cols = Mp if per_app else 1
+    return pl.pallas_call(
+        functools.partial(_crms_kernel, n_apps=n_apps, per_app=per_app),
+        grid=(nb,),
+        in_specs=[
+            # caps_cpu, power_span, alpha, beta
+            pl.BlockSpec((4,), _scalars, memory_space=pltpu.SMEM),
+            pl.BlockSpec((3, Mp), _whole),
+            pl.BlockSpec((1, Mp), _whole),
+            pl.BlockSpec((1, Mp), _whole),
+            pl.BlockSpec((CB, Mp), _row_tile),
+            pl.BlockSpec((CB, Mp), _row_tile),
+            pl.BlockSpec((CB, Mp), _row_tile),
+        ],
+        out_specs=pl.BlockSpec((CB, out_cols), _row_tile),
+        out_shape=jax.ShapeDtypeStruct((nb * CB, out_cols), jnp.float32),
+        interpret=interpret,
+    )
+
+
+def _xp(*xs):
+    """NumPy where every input is on the host, so building the kernel's
+    operands dispatches nothing to the device; ``jnp`` for device arrays and
+    tracers."""
+    return jnp if any(isinstance(x, jax.Array) for x in xs) else np
 
 
 def crms_grid_eval(kappa, lam, xbar, n, c, m, *, caps_cpu, power_span, alpha, beta,
@@ -113,45 +157,27 @@ def crms_grid_eval(kappa, lam, xbar, n, c, m, *, caps_cpu, power_span, alpha, be
     per_app = reduce == "per_app"
     B, M = n.shape
     Mp = max(8 * ((M + 7) // 8), 8)  # lane-pad the app axis
-
-    def pad_apps(x, fill):
-        return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, Mp - M)), constant_values=fill)
-
-    kpad = jnp.pad(kappa.T.astype(jnp.float32), ((0, 0), (0, Mp - M)), constant_values=1.0)
-    lpad = jnp.pad(lam.astype(jnp.float32)[None, :], ((0, 0), (0, Mp - M)), constant_values=1.0)
-    xpad = jnp.pad(xbar.astype(jnp.float32)[None, :], ((0, 0), (0, Mp - M)), constant_values=1.0)
-    # pad candidates: n=2, c=m=1 keeps padded columns finite; they are masked out
-    npad = pad_apps(n, 2.0)
-    cpad = pad_apps(c, 1.0)
-    mpad = pad_apps(m, 1.0)
     CB = min(block, B)
     nb = pl.cdiv(B, CB)
     pad_b = nb * CB - B
-    if pad_b:
-        npad = jnp.pad(npad, ((0, pad_b), (0, 0)), constant_values=2.0)
-        cpad = jnp.pad(cpad, ((0, pad_b), (0, 0)), constant_values=1.0)
-        mpad = jnp.pad(mpad, ((0, pad_b), (0, 0)), constant_values=1.0)
+    xp = _xp(kappa, lam, xbar, n, c, m)
 
-    kernel = functools.partial(
-        _crms_kernel, caps_cpu=float(caps_cpu), power_span=float(power_span),
-        alpha=float(alpha), beta=float(beta), n_apps=M, per_app=per_app,
-    )
-    out_cols = Mp if per_app else 1
-    u = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((3, Mp), _whole),
-            pl.BlockSpec((1, Mp), _whole),
-            pl.BlockSpec((1, Mp), _whole),
-            pl.BlockSpec((CB, Mp), _row_tile),
-            pl.BlockSpec((CB, Mp), _row_tile),
-            pl.BlockSpec((CB, Mp), _row_tile),
-        ],
-        out_specs=pl.BlockSpec((CB, out_cols), _row_tile),
-        out_shape=jax.ShapeDtypeStruct((nb * CB, out_cols), jnp.float32),
-        interpret=interpret,
-    )(kpad, lpad, xpad, npad, cpad, mpad)
+    def pad(x, rows, fill):
+        return xp.pad(xp.asarray(x, dtype=np.float32), ((0, rows), (0, Mp - M)),
+                      constant_values=fill)
+
+    kpad = pad(xp.transpose(kappa), 0, 1.0)
+    lpad = pad(xp.reshape(lam, (1, M)), 0, 1.0)
+    xpad = pad(xp.reshape(xbar, (1, M)), 0, 1.0)
+    # pad candidates: n=2, c=m=1 keeps padded columns finite; they are masked out
+    npad = pad(n, pad_b, 2.0)
+    cpad = pad(c, pad_b, 1.0)
+    mpad = pad(m, pad_b, 1.0)
+    scalars = (caps_cpu, power_span, alpha, beta)
+    s = _xp(*scalars).asarray(scalars, dtype=np.float32)  # x64 is on: say float32
+
+    u = _grid_call(nb, CB, Mp, M, per_app, bool(interpret))(
+        s, kpad, lpad, xpad, npad, cpad, mpad)
     if per_app:
         return u[:B, :M]
     return u[:B, 0]

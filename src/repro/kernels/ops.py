@@ -98,9 +98,9 @@ def crms_grid(kappa, lam, xbar, n, c, m, *, caps_cpu, power_span, alpha, beta,
         )
     from repro.kernels.crms_grid import crms_grid_eval
 
+    # host arrays stay on the host: the kernel's operands are built in NumPy
     return crms_grid_eval(
-        jnp.asarray(kappa), jnp.asarray(lam), jnp.asarray(xbar),
-        jnp.asarray(n), jnp.asarray(c), jnp.asarray(m),
+        kappa, lam, xbar, n, c, m,
         caps_cpu=caps_cpu, power_span=power_span, alpha=alpha, beta=beta,
         interpret=(mode == "interpret"), reduce=reduce,
     )

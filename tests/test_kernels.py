@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.crms_grid import crms_grid_eval
 
 KEY = jax.random.PRNGKey(0)
 
@@ -107,9 +108,9 @@ def test_ssd_chunked_equals_sequential_recurrence():
 # ---------------------------------------------------------------------------
 # CRMS grid
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("M,B", [(4, 200), (7, 64)])
-def test_crms_grid_interpret_vs_oracle(M, B):
-    rng = np.random.default_rng(0)
+def _grid_inputs(M, B, seed):
+    """kappa, lam, xbar (per app) and n, c, m (per candidate) of a random node."""
+    rng = np.random.default_rng(seed)
     kappa = np.stack(
         [rng.uniform(20, 120, M), rng.uniform(0.8, 2.5, M), rng.uniform(0.2, 0.5, M)], axis=1
     )
@@ -118,6 +119,12 @@ def test_crms_grid_interpret_vs_oracle(M, B):
     n = rng.integers(3, 12, (B, M)).astype(float)
     c = rng.uniform(0.5, 3.0, (B, M))
     m = rng.uniform(0.25, 0.5, (B, M))
+    return kappa, lam, xbar, n, c, m
+
+
+@pytest.mark.parametrize("M,B", [(4, 200), (7, 64)])
+def test_crms_grid_interpret_vs_oracle(M, B):
+    kappa, lam, xbar, n, c, m = _grid_inputs(M, B, seed=0)
     kw = dict(caps_cpu=30.0, power_span=150.0, alpha=1.4, beta=0.2)
     u_int = np.asarray(ops.crms_grid(kappa, lam, xbar, n, c, m, backend="interpret", **kw))
     u_ref = np.asarray(ops.crms_grid(kappa, lam, xbar, n, c, m, backend="reference", **kw))
@@ -131,15 +138,7 @@ def test_crms_grid_interpret_vs_oracle(M, B):
 @pytest.mark.parametrize("M,B", [(4, 96), (7, 40)])
 def test_crms_grid_per_app_interpret_vs_oracle(M, B):
     """Per-app output mode (grid seeding's argmin input) vs the jnp oracle."""
-    rng = np.random.default_rng(1)
-    kappa = np.stack(
-        [rng.uniform(20, 120, M), rng.uniform(0.8, 2.5, M), rng.uniform(0.2, 0.5, M)], axis=1
-    )
-    lam = rng.uniform(4, 12, M)
-    xbar = rng.uniform(4, 6, M)
-    n = rng.integers(3, 12, (B, M)).astype(float)
-    c = rng.uniform(0.5, 3.0, (B, M))
-    m = rng.uniform(0.25, 0.5, (B, M))
+    kappa, lam, xbar, n, c, m = _grid_inputs(M, B, seed=1)
     kw = dict(caps_cpu=30.0, power_span=150.0, alpha=1.4, beta=0.2)
     t_int = np.asarray(
         ops.crms_grid(kappa, lam, xbar, n, c, m, backend="interpret", reduce="per_app", **kw)
@@ -156,3 +155,31 @@ def test_crms_grid_per_app_interpret_vs_oracle(M, B):
     # summed mode is the row-sum of per-app mode
     u_int = np.asarray(ops.crms_grid(kappa, lam, xbar, n, c, m, backend="interpret", **kw))
     np.testing.assert_allclose(u_int, t_int.sum(axis=1), rtol=1e-5)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "per_app"])
+def test_crms_grid_scalars_are_operands(reduce):
+    """Two sets of caps, power span and weights, back to back at one shape (one
+    built kernel): each result follows its own scalars, none the first call's."""
+    M, B = 4, 56
+    args = _grid_inputs(M, B, seed=2)
+    oracle = ref.crms_grid_terms if reduce == "per_app" else ref.crms_grid_utility
+    for kw in (dict(caps_cpu=30.0, power_span=150.0, alpha=1.4, beta=0.2),
+               dict(caps_cpu=120.0, power_span=40.0, alpha=0.6, beta=2.5)):
+        got = np.asarray(crms_grid_eval(*args, interpret=True, reduce=reduce, **kw))
+        want = np.asarray(oracle(*map(jnp.asarray, args), **kw))
+        finite = np.isfinite(want) & (want < 1e8)
+        assert finite.sum() >= 4
+        np.testing.assert_allclose(got[finite], want[finite], rtol=1e-4)
+        assert np.all(got[~finite] > 1e6)
+
+
+def test_crms_grid_eager_and_jitted_agree_bitwise():
+    """The eager call (its own built kernel) and the kernel inlined into an
+    outer jit, with the inputs and the four scalars traced, give the same bits."""
+    args = _grid_inputs(4, 72, seed=3)
+    kw = dict(caps_cpu=108.0, power_span=150.0, alpha=1.4, beta=0.2)
+    eager = np.asarray(crms_grid_eval(*args, interpret=True, reduce="per_app", **kw))
+    jitted = jax.jit(lambda args, kw: crms_grid_eval(
+        *args, interpret=True, reduce="per_app", **kw))(args, kw)
+    np.testing.assert_array_equal(eager, np.asarray(jitted))

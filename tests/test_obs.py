@@ -147,21 +147,25 @@ def test_retrace_markers_fire_on_a_new_shape_only(tmp_path):
     scan = lambda: des_vector.segment_scan(
         np.zeros((m, srv)), np.ones((m, srv), dtype=bool), np.ones((k, m)),
         np.full((k, m), 0.5), np.ones((k, m), dtype=bool))
-    b, mm = 16, 4
-    grid = lambda: crms_grid_eval(
+    mm = 4  # grid shapes (16 and 24 candidates) no other caller uses
+    scalars = dict(caps_cpu=30.0, power_span=10.0, alpha=1.4, beta=0.2)
+    grid = lambda b=16, **kw: crms_grid_eval(
         np.ones((mm, 3), np.float32), np.full(mm, 6.0), np.ones(mm), np.full((b, mm), 3.0),
-        np.ones((b, mm)), np.ones((b, mm)), caps_cpu=30.0, power_span=10.0, alpha=1.4,
-        beta=0.2, interpret=True, reduce="per_app")
+        np.ones((b, mm)), np.ones((b, mm)), interpret=True, reduce="per_app",
+        **{**scalars, **kw})
     with jax.profiler.trace(str(tmp_path)):
         first = solve(), scan()
         second = solve(), scan()
         grid(), grid()
+        grid(caps_cpu=12.0, power_span=40.0, alpha=0.7, beta=0.9)  # scalars are operands
+        grid(b=24)
     spans = _read(tmp_path)
 
     assert [s["stats"]["shape"] for s in _named(spans, "retrace.ip_solve")] == ["3x8"]
     assert [s["stats"]["shape"] for s in _named(spans, "retrace.segment_scan")] == ["24x3x5"]
-    # the eager Pallas call traces its kernel anew on every call
-    assert [s["stats"]["shape"] for s in _named(spans, "retrace.crms_grid")] == ["16x8"] * 2
+    # the Pallas call is built once per shape: other scalars trace nothing, a
+    # new candidate count traces once
+    assert [s["stats"]["shape"] for s in _named(spans, "retrace.crms_grid")] == ["16x8", "24x8"]
     np.testing.assert_array_equal(first[0].r_cpu, second[0].r_cpu)
     np.testing.assert_array_equal(first[1][1], second[1][1])
 
